@@ -25,6 +25,8 @@ def main() -> None:
     ap.add_argument("--json", default=None,
                     help="also write a structured JSON report (CI artifact)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    print(f"# compile cache: {use_compile_cache()}", file=sys.stderr)
 
     bc = BenchConfig()
     if args.full:

@@ -51,8 +51,8 @@ for query, res in zip(batch, store.search(batch)):
     print(f"roles {query.roles} k={query.k} -> {res.ids}  (path={res.path})")
 
 # 6. the same store on the TPU kernel engine, sharded across a mesh ----------
-# (interpret-mode Pallas on CPU; the identical call sites compile to the
-#  real kernel on TPU — see DESIGN.md §3 and §Sharded Execution)
+# (the Pallas kernel is interpreted on CPU and compiled on a TPU backend;
+#  see DESIGN.md §3 and §Sharded Execution)
 from repro.ann.scorescan import scorescan_factory
 from repro.launch.mesh import DeviceMesh
 kstore = build_vector_storage(result, vectors,

@@ -37,7 +37,7 @@ def _live_invars(closed_jaxpr) -> List[bool]:
     reach an output?  Opaque primitives (pallas_call etc.) conservatively
     need all their inputs; call-like primitives recurse via their
     sub-jaxpr params so a truly dead operand stays dead."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     jaxpr = closed_jaxpr.jaxpr
 

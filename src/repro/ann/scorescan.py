@@ -8,8 +8,8 @@ query ``q`` the triangle inequality gives ``dist(q, v) >= (|q-c| - rho)^2``
 for all members, so a node whose lower bound exceeds the global k-th
 distance is skipped without touching HBM.
 
-On this CPU container the kernel runs in interpret mode; on TPU the same
-call sites compile to the real kernel (config.interpret=False).
+The backend picks the kernel mode: compiled on TPU, interpreted on CPU
+(see ``kernels/l2_topk/ops.py``).
 """
 from __future__ import annotations
 

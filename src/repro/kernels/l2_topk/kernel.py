@@ -39,18 +39,23 @@ IMAX = 2 ** 31 - 1          # as traced kernel constants, which pallas rejects
 
 def _extract_topk(dist, ids, k: int, kpad: int):
     """Row-wise smallest-k of (dist, ids) without gathers. Returns sorted
-    (BQ, kpad) arrays (slots past k stay +inf / -1)."""
+    (BQ, kpad) arrays (slots past k stay +inf / -1).
+
+    Slot ``t`` is written by a lane-iota select, not an indexed update: an
+    ``.at[:, t].set`` lowers to ``scatter``, which Mosaic cannot compile."""
     bq = dist.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bq, kpad), 1)
     out_d = jnp.full((bq, kpad), INF, dtype=jnp.float32)
     out_i = jnp.full((bq, kpad), -1, dtype=jnp.int32)
     for t in range(k):
-        m = jnp.min(dist, axis=1)                                  # (BQ,)
-        sel = jnp.min(jnp.where(dist == m[:, None], ids,
-                                jnp.int32(IMAX)), axis=1)
+        m = jnp.min(dist, axis=1, keepdims=True)                   # (BQ, 1)
+        sel = jnp.min(jnp.where(dist == m, ids, jnp.int32(IMAX)),
+                      axis=1, keepdims=True)
         alive = jnp.isfinite(m)
-        out_d = out_d.at[:, t].set(jnp.where(alive, m, jnp.float32(INF)))
-        out_i = out_i.at[:, t].set(jnp.where(alive, sel, jnp.int32(-1)))
-        dist = jnp.where(ids == sel[:, None], jnp.float32(INF), dist)
+        slot = lane == t
+        out_d = jnp.where(slot, jnp.where(alive, m, jnp.float32(INF)), out_d)
+        out_i = jnp.where(slot, jnp.where(alive, sel, jnp.int32(-1)), out_i)
+        dist = jnp.where(ids == sel, jnp.float32(INF), dist)
     return out_d, out_i
 
 
@@ -77,8 +82,13 @@ def _l2_topk_kernel(n_total_ref,
     db = db_ref[...]                                 # (BN, d)
     qn = qn_ref[...]                                 # (BQ, 1)
     dbn = dbn_ref[...]                               # (1, BN)
+    # HIGHEST, not Mosaic's default: on a v5e the default f32 contraction
+    # is a single bfloat16 pass, off by units on distances between vectors
+    # of squared norm ~3,000, enough to swap neighbours; the full-f32
+    # contraction stays within 1e-3 of float64
     dist = qn + dbn - 2.0 * jax.lax.dot_general(
         q, db, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)          # (BQ, BN) via MXU
 
     bq = q.shape[0]
@@ -118,8 +128,8 @@ def _l2_topk_kernel(n_total_ref,
                                              "interpret"))
 def l2_topk_pallas(queries: jax.Array, db: jax.Array, auth_words: jax.Array,
                    role_mask: jax.Array, bound: jax.Array, n_total: int,
-                   k: int, kpad: int = 128, bq: int = 8, bn: int = 512,
-                   interpret: bool = True,
+                   k: int, *, interpret: bool, kpad: int = 128, bq: int = 8,
+                   bn: int = 512,
                    attr_words: jax.Array = None, require: jax.Array = None,
                    forbid: jax.Array = None) -> Tuple[jax.Array, jax.Array]:
     """Launch the kernel on padded operands (see ops.l2_topk for padding).

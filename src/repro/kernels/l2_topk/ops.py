@@ -1,10 +1,11 @@
 """jit'd public wrapper for the authorized L2 top-k scan kernel.
 
-Handles padding (queries to BQ, db to BN, d to 128 lanes), masks padded
+Handles padding (queries to BQ, db to BN, d to 128 lanes) and masks padded
 database rows via the in-kernel validity predicate (all-zero auth words) and
-padded query rows via all-zero role masks (+inf bounds), and exposes an
-``interpret`` switch so the kernel body runs in Python on CPU for validation
-while targeting TPU VMEM tiling in production.
+padded query rows via all-zero role masks (+inf bounds).  The backend picks
+the kernel mode (:func:`kernel_interpret`): on TPU every launch runs the
+compiled Mosaic kernel, on CPU the same kernel body runs in the Pallas
+interpreter (the test suite's mode), and any other backend is an error.
 
 Auth masks are single-word (``(N,)`` + scalar/``(B,)`` role mask — role
 universes up to 32 roles, the original layout) or multi-word (``(N, W)``
@@ -33,7 +34,20 @@ class L2TopKConfig:
     bn: int = 512          # database tile rows (VMEM-resident)
     kpad: int = 128        # running top-k storage width (lane aligned)
     lane: int = 128        # feature padding multiple (MXU alignment)
-    interpret: bool = True  # CPU container default; False on real TPU
+
+
+def kernel_interpret() -> bool:
+    """Kernel mode for the default backend: compiled on ``tpu``, the Pallas
+    interpreter on ``cpu``.  Any other backend raises — a launch never falls
+    back to a mode the backend was not asked for."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"l2_topk runs compiled on TPU or interpreted on CPU; the default "
+        f"JAX backend is {backend!r}")
 
 
 def _pad_to(x: jax.Array, m: int, axis: int, value=0):
@@ -76,7 +90,7 @@ def l2_topk(queries: jax.Array, db: jax.Array, auth_bits: jax.Array,
     b, d = queries.shape
     n = db.shape[0]
     if bound is None:
-        bound = jnp.float32(jnp.inf)
+        bound = np.float32(np.inf)
     auth, mask, w = normalize_masks(auth_bits, role_mask)
     pred = normalize_predicates(attr_bits, require, forbid)
     qp = _pad_to(queries.astype(jnp.float32), config.bq, 0)
@@ -106,7 +120,7 @@ def l2_topk(queries: jax.Array, db: jax.Array, auth_bits: jax.Array,
     out_d, out_i = l2_topk_pallas(
         qp, dbp, ap, rp, bp, n, k,
         kpad=config.kpad, bq=config.bq, bn=config.bn,
-        interpret=config.interpret, **pkw)
+        interpret=kernel_interpret(), **pkw)
     return out_d[:b], out_i[:b]
 
 

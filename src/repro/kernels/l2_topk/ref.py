@@ -31,7 +31,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-INF = jnp.float32(jnp.inf)
+INF = float("inf")          # a python float: a jnp constant would allocate on
+                            # the default device as this module is imported
 
 
 def _per_query(x, dtype) -> jax.Array:

@@ -37,14 +37,21 @@ def make_production_mesh(*, multi_pod: bool = False):
     """Full-pod training mesh: (16, 16) single pod or (2, 16, 16) dual pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto_axes(len(axes)))
+
+
+def _auto_axes(n: int):
+    """Auto axis types: the sharding rules place arrays with
+    ``with_sharding_constraint``, which Explicit axes (JAX's default for
+    ``make_mesh``) refuse."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Optional[Tuple[str, ...]] = None):
     """Arbitrary mesh for tests / small dry-runs (e.g. (2,4) on 8 devices)."""
     if axes is None:
         axes = ("pod", "data", "model")[-len(shape):]
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto_axes(len(axes)))
 
 
 # --------------------------------------------------------------------------

@@ -497,3 +497,55 @@ def test_predicate_rows_without_attr_plane_rejected():
     with pytest.raises(ValueError):
         l2_topk(jnp.array(q), jnp.array(db), jnp.array(auth), np.uint32(1),
                 5, require=np.zeros((2, 1), np.uint32))
+
+
+def test_kernel_mode_follows_the_backend(monkeypatch):
+    """The backend alone picks the kernel mode: the CPU backend interprets
+    the kernel, TPU compiles it, and any other backend is refused before a
+    launch — never a silent fallback."""
+    from repro.kernels.l2_topk import ops
+    seen = []
+    inner = ops.l2_topk_pallas
+
+    def spy(*args, **kw):
+        seen.append(kw["interpret"])
+        return inner(*args, **kw)
+
+    monkeypatch.setattr(ops, "l2_topk_pallas", spy)
+    rng = np.random.default_rng(40)
+    q = rng.standard_normal((2, 8)).astype(np.float32)
+    db = rng.standard_normal((64, 8)).astype(np.float32)
+    auth = np.ones(64, np.uint32)
+    d, i = l2_topk(q, db, auth, np.uint32(1), 3)
+    assert seen == [True] and (np.asarray(i) >= 0).all()
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops.kernel_interpret() is False
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        l2_topk(q, db, auth, np.uint32(1), 3)
+    assert seen == [True]
+
+
+def test_importing_repro_allocates_no_device_array():
+    """Importing any repro module leaves no JAX array behind: an array made
+    at import would claim the default device (the chip) in every process
+    that only imports the library."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = (
+        "import importlib, pkgutil, jax, repro\n"
+        "names = [m.name for m in pkgutil.walk_packages(repro.__path__, "
+        "'repro.')]\n"
+        "for n in names: importlib.import_module(n)\n"
+        "from repro.kernels.l2_topk import ref\n"
+        "assert isinstance(ref.INF, float)\n"
+        "live = jax.live_arrays()\n"
+        "assert not live, [a.shape for a in live]\n"
+        "print(len(names))\n")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert int(r.stdout.split()[-1]) > 50

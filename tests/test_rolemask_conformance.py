@@ -110,7 +110,10 @@ def test_sequential_path_matches_authorized_oracle(n_roles, seed):
     policy, vecs, store, _ = _built(n_roles, seed, scan=False)
     queries = _queries(policy, vecs, seed)
     results = store.search(queries)
-    assert all(r.path == "sequential" for r in results)
+    # exact engines take the per-query path; a lattice with no node (every
+    # block a leftover, e.g. 256 roles at seed 0) is batch-capable by design
+    want = "sequential" if store.engines else "batched"
+    assert all(r.path == want for r in results)
     _assert_matches_oracle(policy, vecs, queries, results)
 
 
