@@ -1,0 +1,42 @@
+"""The timed path broken underneath a whole run: ``correct`` comes out
+false.  The faults a retrieval cell can have: an answer altered where it
+is produced (a launch returns another row's id), and half of a batch left
+out (a launch answers only the first half of its query rows)."""
+import numpy as np
+import pytest
+
+from bench.harness import runner
+from repro.ann.scorescan import ScoreScanIndex
+
+from .helpers import BIG_SEED, tiny_cell
+
+
+def altered(inner):
+    def search_masked_batch(self, qs, k, role_masks, bounds=None, **kw):
+        d, i = inner(self, qs, k, role_masks, bounds=bounds, **kw)
+        first = i[:, 0]
+        other = np.where(first == self.ids[0], self.ids[-1], self.ids[0])
+        i[:, 0] = np.where(first >= 0, other, first)
+        return d, i
+    return search_masked_batch
+
+
+def half_left_out(inner):
+    def search_masked_batch(self, qs, k, role_masks, bounds=None, **kw):
+        d, i = inner(self, qs, k, role_masks, bounds=bounds, **kw)
+        h = (len(qs) + 1) // 2
+        d[h:], i[h:] = np.inf, -1
+        return d, i
+    return search_masked_batch
+
+
+@pytest.mark.parametrize("fault", [altered, half_left_out])
+def test_broken_path_is_not_correct(monkeypatch, fault):
+    monkeypatch.setattr(ScoreScanIndex, "search_masked_batch",
+                        fault(ScoreScanIndex.search_masked_batch))
+    code, res = runner.run_cell(tiny_cell("closed128"), BIG_SEED,
+                                1.0, False, platforms=("cpu",))
+    assert code == 0
+    assert res["correct"] is False
+    bad = [n for n, c in res["checks"].items() if c["value"] > c["limit"]]
+    assert bad, res["checks"]
