@@ -102,38 +102,6 @@ class Clock:
         return self.t0 - t
 
 
-class CompileCounter:
-    """Counts XLA compiles through JAX's monitoring events: every compile
-    request (a persistent-cache read included), the cache hits, and the
-    seconds spent in them."""
-
-    def __init__(self):
-        import jax
-        self.requests = 0
-        self.hits = 0
-        self.seconds = 0.0
-
-        def on_event(event, **_):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-
-        def on_duration(event, duration, **_):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.requests += 1
-                self.seconds += duration
-
-        jax.monitoring.register_event_listener(on_event)
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-
-    def snapshot(self):
-        return (self.requests, self.hits, self.seconds)
-
-    def since(self, snap) -> str:
-        r, h, s = (a - b for a, b in zip(self.snapshot(), snap))
-        return (f"compiles={r} cache_hits={h} fresh={r - h} "
-                f"compile_s={s:.3f}")
-
-
 # ------------------------------------------------------------------ oracle
 class Oracle:
     """Float64 brute force over the whole corpus: the answers every search is
@@ -334,6 +302,7 @@ def run_single(sizes: Sizes, seed: int) -> bool:
     import jax
     from repro.core import SearchResult
     from repro.launch.serve import warm_batch_shapes
+    from repro.obs import CompileCounter
 
     clock = Clock()
     compiles = CompileCounter()
@@ -504,6 +473,7 @@ def run_sharded(sizes: Sizes, seed: int, chips: int) -> bool:
     import jax
     from repro.launch.mesh import DeviceMesh
     from repro.launch.serve import warm_batch_shapes
+    from repro.obs import CompileCounter
 
     clock = Clock()
     compiles = CompileCounter()

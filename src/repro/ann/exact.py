@@ -18,11 +18,9 @@ class ExactIndex:
         self.ids = (np.arange(len(data), dtype=np.int64) if ids is None
                     else np.asarray(ids, dtype=np.int64))
         self._norms = np.einsum("nd,nd->n", self.data, self.data)
-        self._distance_computations = 0
 
     def _all_dists(self, q: np.ndarray) -> np.ndarray:
         q = np.asarray(q, dtype=np.float32)
-        self._distance_computations += len(self.data)
         return self._norms - 2.0 * (self.data @ q) + float(q @ q)
 
     def purged(self, drop) -> "ExactIndex":

@@ -102,7 +102,6 @@ class HNSWIndex:
         self.neighbors: List[Dict[int, List[int]]] = []
         self.entry: int = -1
         self.max_level: int = -1
-        self._distance_computations = 0
         self.tombstoned: set = set()        # external ids marked deleted
         for i in range(self._n):
             self._insert(i)
@@ -162,12 +161,10 @@ class HNSWIndex:
     # ------------------------------------------------------------- distances
     def _dist(self, q: np.ndarray, idx: Sequence[int]) -> np.ndarray:
         idx = np.asarray(idx, dtype=np.int64)
-        self._distance_computations += len(idx)
         diff = self.data[idx] - q
         return np.einsum("nd,nd->n", diff, diff)
 
     def _dist1(self, q: np.ndarray, i: int) -> float:
-        self._distance_computations += 1
         d = self.data[i] - q
         return float(d @ d)
 

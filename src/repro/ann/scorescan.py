@@ -18,6 +18,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..kernels.l2_topk import l2_topk, L2TopKConfig
 
 
@@ -60,7 +61,6 @@ class ScoreScanIndex:
         else:
             self.radius = 0.0
             self._centered = self.data
-        self._distance_computations = 0
 
     def __len__(self) -> int:
         return len(self.data)
@@ -118,7 +118,6 @@ class ScoreScanIndex:
         """
         if not len(self.data):
             return []
-        self._distance_computations += len(self.data)
         qc = (q - self.centroid).astype(np.float32)
         d, i = l2_topk(qc[None, :], self._centered, self.auth_bits,
                        np.asarray(role_mask, np.uint32), k, bound=bound,
@@ -154,20 +153,18 @@ class ScoreScanIndex:
         if not len(self.data):
             return (np.full((b, k), np.inf, np.float32),
                     np.full((b, k), -1, np.int64))
-        self._distance_computations += len(self.data) * b
-        qc = (np.asarray(qs, np.float32) - self.centroid).astype(np.float32)
-        d, i = l2_topk(qc, self._centered, self.auth_bits,
-                       np.asarray(role_masks, np.uint32), k,
-                       bound=None if bounds is None
-                       else np.asarray(bounds, np.float32),
-                       config=self.config,
-                       **self._pred_kwargs(require, forbid))
-        # np.array (not asarray): jax buffers are read-only and callers
-        # post-filter these in place
-        d = np.array(d)
-        i = np.asarray(i)
-        ext = np.where(i >= 0, self.ids[np.maximum(i, 0)], np.int64(-1))
-        return d, ext
+        pkw = self._pred_kwargs(require, forbid)
+        with obs.span("scan.launch", n=len(self.data), b=b,
+                      w=self.mask_width,
+                      p=pkw["attr_bits"].shape[1] if pkw else 0, k=k):
+            qc = (np.asarray(qs, np.float32) - self.centroid).astype(
+                np.float32)
+            d, i = l2_topk(qc, self._centered, self.auth_bits,
+                           np.asarray(role_masks, np.uint32), k,
+                           bound=None if bounds is None
+                           else np.asarray(bounds, np.float32),
+                           config=self.config, **pkw)
+            return read_back(d, i, self.ids)
 
     def purged(self, drop) -> "ScoreScanIndex":
         """Copy of this index with the rows whose external id is in ``drop``
@@ -196,6 +193,18 @@ class ScoreScanIndex:
         res = self.search_masked(q, max(efs, 1), role_mask=self._full_mask())
         internal = {int(e): j for j, e in enumerate(self.ids)}
         return [(dd, internal[vid]) for dd, vid in res]
+
+
+def read_back(d, i, ids: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """A launch's ``(dists, ids)`` on the host, row ids made external: the
+    host waits here for the kernel and the copy back (span
+    ``scan.readback``)."""
+    with obs.span("scan.readback"):
+        # np.array (not asarray): jax buffers are read-only and callers
+        # post-filter these in place
+        d = np.array(d)
+        i = np.asarray(i)
+        return d, np.where(i >= 0, ids[np.maximum(i, 0)], np.int64(-1))
 
 
 def policy_auth_words(policy) -> np.ndarray:

@@ -40,6 +40,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from .api import Query, SearchResult, SearchStats
 from .queryplan import Plan
 from .store import VectorStore
@@ -149,7 +150,8 @@ def _scan_leftovers_batched(store: VectorStore, queries: np.ndarray,
         # predicate-pruned slots carry +inf — drop their ids so they never
         # surface through the merge
         sel_i = np.where(np.isinf(sel_d), np.int64(-1), sel_i)
-        topk.push_rows(rows, sel_d, sel_i)
+        with obs.span("search.merge", rows=len(rows)):
+            topk.push_rows(rows, sel_d, sel_i)
 
 
 def _filter_unauthorized(d: np.ndarray, ids: np.ndarray, rows: np.ndarray,
@@ -215,8 +217,9 @@ def _scan_leftovers_packed(store: VectorStore, queries: np.ndarray,
                                        role_bits[rows], **pkw)
     # defense in depth: the shard's word masks are exact at any n_roles
     # (multi-word past 32 roles), but the bool mask stays the ground truth
-    _filter_unauthorized(d, ids, rows, row_masks)
-    topk.push_rows(rows, d, ids)
+    with obs.span("search.merge", rows=len(rows)):
+        _filter_unauthorized(d, ids, rows, row_masks)
+        topk.push_rows(rows, d, ids)
 
 
 def _prepare_batch(store: VectorStore, queries: Sequence[Query]):
@@ -310,8 +313,14 @@ def execute_queries(store: VectorStore, queries: Sequence[Query], *,
     ``coordinated_scan_search(store, q.vector, q.roles, q.k)``.
     """
     b = len(queries)
-    (qs, ks, kmax, role_sets, plans, row_masks, role_bits,
-     stats_rows, pred_rows, pred_masks) = _prepare_batch(store, queries)
+    with obs.span("search.plan", rows=b) as sp:
+        (qs, ks, kmax, role_sets, plans, row_masks, role_bits,
+         stats_rows, pred_rows, pred_masks) = _prepare_batch(store, queries)
+        # invert plans: node -> rows, split per (row, node) purity against
+        # the row's (multi-role) authorized mask
+        pure_rows, impure_rows, sizes_cache = _classify_waves(
+            store, plans, role_sets, row_masks, stats_rows)
+        sp.set(role_sets=len(set(role_sets)))
 
     topk = BatchTopK(b, kmax, ks=ks)
     if packed is True:
@@ -321,45 +330,46 @@ def execute_queries(store: VectorStore, queries: Sequence[Query], *,
     else:
         shard = None
     path = "batched+packed" if shard is not None else "batched"
-    if shard is not None:
-        _scan_leftovers_packed(store, qs, plans, row_masks, role_bits,
-                               topk, stats_rows, shard, pred_rows=pred_rows)
-    else:
-        _scan_leftovers_batched(store, qs, plans, topk, stats_rows,
-                                pred_masks=pred_masks)
-
-    # invert plans: node -> rows, split per (row, node) purity against the
-    # row's (multi-role) authorized mask
-    pure_rows, impure_rows, sizes_cache = _classify_waves(
-        store, plans, role_sets, row_masks, stats_rows)
+    with obs.span("search.leftovers", path=path):
+        if shard is not None:
+            _scan_leftovers_packed(store, qs, plans, row_masks, role_bits,
+                                   topk, stats_rows, shard,
+                                   pred_rows=pred_rows)
+        else:
+            _scan_leftovers_batched(store, qs, plans, topk, stats_rows,
+                                    pred_masks=pred_masks)
 
     def _wave(groups: Dict, impure: bool) -> None:
         # nearest-first across the batch: tightening close rows' bounds early
         # maximizes later skips, like the per-query ascending-lb order
         keyed = []
-        for key, rows in groups.items():
-            eng = store.engines[key]
-            rows = np.asarray(rows)
-            lbs = eng.lower_bounds(qs[rows])
-            keyed.append((float(lbs.min()), key, rows, lbs))
-        keyed.sort(key=lambda t: t[0])
+        with obs.span("search.bound", nodes=len(groups)):
+            for key, rows in groups.items():
+                eng = store.engines[key]
+                rows = np.asarray(rows)
+                lbs = eng.lower_bounds(qs[rows])
+                keyed.append((float(lbs.min()), key, rows, lbs))
+            keyed.sort(key=lambda t: t[0])
         for _, key, rows, lbs in keyed:
             eng = store.engines[key]
-            for qi in rows:
-                st = stats_rows[qi]
-                if impure:
-                    total, auth = sizes_cache[(key, role_sets[qi])]
-                    st.impure_visits += 1
-                else:
-                    total = auth = len(eng)
-                st.data_touched += total
-                st.data_authorized_touched += auth
-            kth = topk.kth(rows)
-            active = lbs <= kth
-            for qi in rows[~active]:
-                stats_rows[qi].phase2_skipped += 1
-                if not impure:
-                    stats_rows[qi].impure_visits += 1  # bound-skip opportunity
+            with obs.span("search.bound", rows=len(rows)) as sp:
+                for qi in rows:
+                    st = stats_rows[qi]
+                    if impure:
+                        total, auth = sizes_cache[(key, role_sets[qi])]
+                        st.impure_visits += 1
+                    else:
+                        total = auth = len(eng)
+                    st.data_touched += total
+                    st.data_authorized_touched += auth
+                kth = topk.kth(rows)
+                active = lbs <= kth
+                for qi in rows[~active]:
+                    stats_rows[qi].phase2_skipped += 1
+                    if not impure:
+                        # bound-skip opportunity
+                        stats_rows[qi].impure_visits += 1
+                sp.set(active=int(active.sum()))
             if not active.any():
                 continue
             act = rows[active]
@@ -368,13 +378,17 @@ def execute_queries(store: VectorStore, queries: Sequence[Query], *,
             d, ids = eng.search_masked_batch(qs[act], kmax,
                                              role_bits[act],
                                              bounds=kth[active], **pkw)
-            if impure:
-                _filter_unauthorized(d, ids, act, row_masks)
-            topk.push_rows(act, d, ids)
+            with obs.span("search.merge", rows=len(act)):
+                if impure:
+                    _filter_unauthorized(d, ids, act, row_masks)
+                topk.push_rows(act, d, ids)
 
-    _wave(pure_rows, impure=False)
-    _wave(impure_rows, impure=True)
-    items = topk.items()
-    return [SearchResult(hits=items[i][:int(ks[i])], stats=stats_rows[i],
-                         path=path)
-            for i in range(b)]
+    for groups, impure in ((pure_rows, False), (impure_rows, True)):
+        with obs.span("search.wave", kind="impure" if impure else "pure",
+                      nodes=len(groups)):
+            _wave(groups, impure)
+    with obs.span("search.merge", rows=b):
+        items = topk.items()
+        return [SearchResult(hits=items[i][:int(ks[i])],
+                             stats=stats_rows[i], path=path)
+                for i in range(b)]

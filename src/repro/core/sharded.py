@@ -42,13 +42,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from collections import defaultdict
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from .api import (DEFAULT_MIN_PACKED_BATCH, Query, QueryLike, SearchResult,
                   as_queries)
 from .batched import (BatchTopK, _classify_waves, _filter_unauthorized,
@@ -242,6 +242,7 @@ class DeviceShard:
             return (np.full((b, k), np.inf, np.float32),
                     np.full((b, k), -1, np.int64))
         import jax
+        from ..ann.scorescan import read_back
         from ..kernels.l2_topk import l2_topk
         # identical fp preparation to the parent engine (bit-exact parity)
         qc = (np.asarray(qs, np.float32) - self.centroid).astype(np.float32)
@@ -262,10 +263,7 @@ class DeviceShard:
                                       self.device))
         d, i = l2_topk(qd, self._data_dev, self._auth_dev, md, k,
                        bound=bd, config=self.config, **pkw)
-        d = np.array(d)
-        i = np.asarray(i)
-        ext = np.where(i >= 0, self.ids[np.maximum(i, 0)], np.int64(-1))
-        return d, ext
+        return read_back(d, i, self.ids)
 
 
 # -------------------------------------------------------------- the store
@@ -350,8 +348,10 @@ class ShardedVectorStore:
             ThreadPoolExecutor(max_workers=1,
                                thread_name_prefix=f"mesh-slot{i}")
             for i in range(self.mesh.size)]
-        # per-slot occupancy accounting; each slot's entry is only mutated
-        # by that slot's single worker thread, so no lock is needed
+        # per-slot launch accounting; each slot's entry is only mutated by
+        # that slot's single worker thread, so no lock is needed.
+        # device_busy_s is host seconds inside launches (the slot's
+        # scan.launch spans summed), not device time
         self.device_busy_s: List[float] = [0.0] * self.mesh.size
         self.device_launches: List[int] = [0] * self.mesh.size
         self._closed = False
@@ -377,7 +377,8 @@ class ShardedVectorStore:
         yield from self.leftover_shards
 
     def device_stats(self) -> Dict[int, Dict[str, float]]:
-        """Cumulative per-slot occupancy: busy seconds + launch counts."""
+        """Cumulative per-slot launches: ``busy_s``, host seconds inside
+        the slot's launches (its ``scan.launch`` spans), and counts."""
         return {i: {"busy_s": self.device_busy_s[i],
                     "launches": float(self.device_launches[i])}
                 for i in range(self.mesh.size)}
@@ -413,16 +414,20 @@ class ShardedVectorStore:
         """Enqueue one shard launch on its slot's stream; returns a future
         resolving to the shard's ``(dists, ids)`` block."""
         slot = shard.slot
+        # the launch runs on the slot's thread, in the caller's span tree
+        parent = obs.current()
 
         def run():
-            t0 = time.perf_counter()
+            sp = obs.span("scan.launch", slot=slot, n=len(shard), b=len(qs),
+                          k=k)
             try:
-                return shard.search_masked_batch(qs, k, role_rows,
-                                                 bounds=bounds,
-                                                 require=require,
-                                                 forbid=forbid)
+                with obs.within(parent), sp:
+                    return shard.search_masked_batch(qs, k, role_rows,
+                                                     bounds=bounds,
+                                                     require=require,
+                                                     forbid=forbid)
             finally:
-                self.device_busy_s[slot] += time.perf_counter() - t0
+                self.device_busy_s[slot] += sp.seconds
                 self.device_launches[slot] += 1
         return self._executors[slot].submit(run)
 
@@ -452,8 +457,12 @@ class ShardedVectorStore:
                  min_packed_batch: int) -> List[SearchResult]:
         store = self.store
         b = len(queries)
-        (qs, ks, kmax, role_sets, plans, row_masks, role_bits,
-         stats_rows, pred_rows, pred_masks) = _prepare_batch(store, queries)
+        with obs.span("search.plan", rows=b):
+            (qs, ks, kmax, role_sets, plans, row_masks, role_bits,
+             stats_rows, pred_rows, pred_masks) = _prepare_batch(store,
+                                                                 queries)
+            pure_rows, impure_rows, sizes_cache = _classify_waves(
+                store, plans, role_sets, row_masks, stats_rows)
         topk = BatchTopK(b, kmax, ks=ks)
 
         # mirror the batched engine's path semantics: "+packed" only when a
@@ -462,35 +471,40 @@ class ShardedVectorStore:
         use_packed = bool(self.leftover_shards) and (
             packed is True or (packed is None and b >= min_packed_batch))
         path = "sharded+packed" if use_packed else "sharded"
-        if use_packed:
-            rows = _packed_leftover_rows(store, plans, stats_rows)
-            if len(rows):
-                req = forb = None
-                if pred_rows is not None:
-                    req, forb = pred_rows[0][rows], pred_rows[1][rows]
-                futs = [self._submit(s, qs[rows], topk.k, role_bits[rows],
-                                     np.full(len(rows), np.inf, np.float32),
-                                     require=req, forbid=forb)
-                        for s in self.leftover_shards]
-                for fut in futs:
-                    d, ids = fut.result()
-                    # defense in depth, same as the single-shard packed path
-                    _filter_unauthorized(d, ids, rows, row_masks)
-                    topk.push_rows(rows, d, ids)
-        else:
-            _scan_leftovers_batched(store, qs, plans, topk, stats_rows,
-                                    pred_masks=pred_masks)
+        with obs.span("search.leftovers", path=path):
+            if use_packed:
+                rows = _packed_leftover_rows(store, plans, stats_rows)
+                if len(rows):
+                    req = forb = None
+                    if pred_rows is not None:
+                        req, forb = pred_rows[0][rows], pred_rows[1][rows]
+                    futs = [self._submit(s, qs[rows], topk.k,
+                                         role_bits[rows],
+                                         np.full(len(rows), np.inf,
+                                                 np.float32),
+                                         require=req, forbid=forb)
+                            for s in self.leftover_shards]
+                    for fut in futs:
+                        d, ids = fut.result()
+                        # defense in depth, as on the single-shard path
+                        with obs.span("search.merge", rows=len(rows)):
+                            _filter_unauthorized(d, ids, rows, row_masks)
+                            topk.push_rows(rows, d, ids)
+            else:
+                _scan_leftovers_batched(store, qs, plans, topk, stats_rows,
+                                        pred_masks=pred_masks)
 
-        pure_rows, impure_rows, sizes_cache = _classify_waves(
-            store, plans, role_sets, row_masks, stats_rows)
-        self._wave(pure_rows, False, qs, kmax, role_bits, role_sets,
-                   row_masks, sizes_cache, topk, stats_rows, pred_rows)
-        self._wave(impure_rows, True, qs, kmax, role_bits, role_sets,
-                   row_masks, sizes_cache, topk, stats_rows, pred_rows)
-        items = topk.items()
-        return [SearchResult(hits=items[i][:int(ks[i])],
-                             stats=stats_rows[i], path=path)
-                for i in range(b)]
+        for groups, impure in ((pure_rows, False), (impure_rows, True)):
+            with obs.span("search.wave", kind="impure" if impure else "pure",
+                          nodes=len(groups)):
+                self._wave(groups, impure, qs, kmax, role_bits, role_sets,
+                           row_masks, sizes_cache, topk, stats_rows,
+                           pred_rows)
+        with obs.span("search.merge", rows=b):
+            items = topk.items()
+            return [SearchResult(hits=items[i][:int(ks[i])],
+                                 stats=stats_rows[i], path=path)
+                    for i in range(b)]
 
     def _wave(self, groups: Dict, impure: bool, qs, kmax, role_bits,
               role_sets, row_masks, sizes_cache, topk, stats_rows,
@@ -558,9 +572,10 @@ class ShardedVectorStore:
                     require=req, forbid=forb)))
             for key, act, fut in futs:
                 d, ids = fut.result()
-                if impure:
-                    _filter_unauthorized(d, ids, act, row_masks)
-                topk.push_rows(act, d, ids)
+                with obs.span("search.merge", rows=len(act)):
+                    if impure:
+                        _filter_unauthorized(d, ids, act, row_masks)
+                    topk.push_rows(act, d, ids)
         for key, rows in groups.items():
             for qi in rows:
                 if int(qi) not in launched[key]:

@@ -1,11 +1,16 @@
-"""jit'd public wrapper for the authorized L2 top-k scan kernel.
+"""Public wrapper for the authorized L2 top-k scan kernel.
 
-Handles padding (queries to BQ, db to BN, d to 128 lanes) and masks padded
-database rows via the in-kernel validity predicate (all-zero auth words) and
-padded query rows via all-zero role masks (+inf bounds).  The backend picks
-the kernel mode (:func:`kernel_interpret`): on TPU every launch runs the
-compiled Mosaic kernel, on CPU the same kernel body runs in the Pallas
-interpreter (the test suite's mode), and any other backend is an error.
+Only the kernel launch (``kernel.l2_topk_pallas``) is jitted.  The wrapper
+itself runs eagerly on every launch: host operands are cast by numpy and
+reach the device through the first eager JAX operation on them (a mask
+reshape, a pad), or through the kernel call when they need no pad; each
+pad and cast is its own small program.  It pads queries to BQ, db to BN
+and d to 128 lanes, and masks padded database rows via the in-kernel
+validity predicate (all-zero auth words) and padded query rows via
+all-zero role masks (+inf bounds).  The backend picks the kernel mode
+(:func:`kernel_interpret`): on TPU every launch runs the compiled Mosaic
+kernel, on CPU the same kernel body runs in the Pallas interpreter (the
+test suite's mode), and any other backend is an error.
 
 Auth masks are single-word (``(N,)`` + scalar/``(B,)`` role mask — role
 universes up to 32 roles, the original layout) or multi-word (``(N, W)``
@@ -13,6 +18,16 @@ packed uint32 words + ``(W,)``/``(B, W)`` role masks, W = ceil(n_roles/32));
 see DESIGN.md §Role Masks.  W == 1 operands take exactly the original
 single-word kernel path — same block shapes, same compare — so existing
 perf baselines hold.
+
+Spans (``repro.obs``): ``l2_topk.prep`` from entry to the kernel call
+(mask normalising, casts, and the issue of the host-to-device copies, pads
+and casts) and ``l2_topk.dispatch`` around the kernel call and the trim of
+the padded query rows (their enqueue; a compile lands here).  Copies run
+asynchronously, so the host's wait for them falls where it first blocks:
+in either span or in the read-back after them.  Counters on
+``l2_topk.prep``: ``h2d_bytes`` (bytes of the operands that arrive as host
+arrays), ``launches``, ``rows_scanned`` (N) and ``rows_padded`` (database
+rows padded to ``bn`` plus query rows padded to ``bq``).
 """
 from __future__ import annotations
 
@@ -24,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ... import obs
 from .kernel import l2_topk_pallas
 from .ref import l2_topk_ref, normalize_masks, normalize_predicates
 
@@ -87,8 +103,36 @@ def l2_topk(queries: jax.Array, db: jax.Array, auth_bits: jax.Array,
       (dists (B, k) float32, ids (B, k) int32); empty slots are +inf / -1.
     """
     assert k <= config.kpad, (k, config.kpad)
+    with obs.span("l2_topk.prep"):
+        operands, pkw = _prep(queries, db, auth_bits, role_mask, bound,
+                              config, attr_bits, require, forbid)
+    b = queries.shape[0]
+    with obs.span("l2_topk.dispatch"):
+        out_d, out_i = l2_topk_pallas(
+            *operands, db.shape[0], k, kpad=config.kpad, bq=config.bq,
+            bn=config.bn, interpret=kernel_interpret(), **pkw)
+        # the padded query rows are trimmed on the device, also enqueued
+        return out_d[:b], out_i[:b]
+
+
+def _host_bytes(*operands) -> int:
+    """Bytes a launch sends from host to device: every operand that is not
+    a ``jax.Array`` goes to the device as 4-byte float32 or uint32
+    elements."""
+    return sum(4 * np.size(x) for x in operands
+               if x is not None and not isinstance(x, jax.Array))
+
+
+def _prep(queries, db, auth_bits, role_mask, bound, config, attr_bits,
+          require, forbid):
+    """The kernel's padded operands and its predicate keywords."""
+    obs.count("h2d_bytes", _host_bytes(queries, db, auth_bits, role_mask,
+                                       bound, attr_bits, require, forbid))
     b, d = queries.shape
     n = db.shape[0]
+    obs.count("launches")
+    obs.count("rows_scanned", n)
+    obs.count("rows_padded", (-n) % config.bn + (-b) % config.bq)
     if bound is None:
         bound = np.float32(np.inf)
     auth, mask, w = normalize_masks(auth_bits, role_mask)
@@ -117,11 +161,7 @@ def l2_topk(queries: jax.Array, db: jax.Array, auth_bits: jax.Array,
             attr_words=_pad_to(attr.T, config.bn, 1),
             require=_pad_to(jnp.broadcast_to(req, (b, p)), config.bq, 0),
             forbid=_pad_to(jnp.broadcast_to(forb, (b, p)), config.bq, 0))
-    out_d, out_i = l2_topk_pallas(
-        qp, dbp, ap, rp, bp, n, k,
-        kpad=config.kpad, bq=config.bq, bn=config.bn,
-        interpret=kernel_interpret(), **pkw)
-    return out_d[:b], out_i[:b]
+    return (qp, dbp, ap, rp, bp), pkw
 
 
 def l2_topk_oracle(queries, db, auth_bits, role_mask, k, bound=None,

@@ -51,7 +51,7 @@ This module adds the missing layer between callers and the engine:
   * :class:`ServeStats` — the versioned serving-stats contract
     (``summary()`` schema v2): totals, flush reasons, per-SLO-class
     sub-blocks (p50/p99, admitted/rejected/cancelled, cache hit rate),
-    execution paths, device occupancy, and maintenance counters.
+    execution paths, per-device launches, and maintenance counters.
 
 Mixing roles within a micro-batch remains free: the batched engine unions
 plan covers, so co-scheduled roles share kernel launches on overlapping
@@ -72,6 +72,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from .. import obs
 from ..core import (DEFAULT_MIN_PACKED_BATCH, AnswerCache, Outcome, Query,
                     Rejected, SLOClass, SearchResult, SearchStats)
 from ..core.policy import mask_words, roles_word_mask
@@ -168,8 +169,9 @@ class ServeStats:
     # leftover strategy served the batch); cache hits count per request
     # under "cache"
     paths: Dict[str, int] = dataclasses.field(default_factory=dict)
-    # latest per-device occupancy snapshot from a sharded store: device
-    # slot -> cumulative busy seconds / kernel launches
+    # latest per-device launch snapshot from a sharded store: device slot
+    # -> cumulative host seconds inside its launches (not device time) /
+    # kernel launches
     device_busy_s: Dict[int, float] = dataclasses.field(default_factory=dict)
     device_launches: Dict[int, int] = dataclasses.field(default_factory=dict)
     # background maintenance (LatticeCompactor hook): cycles run between
@@ -199,7 +201,8 @@ class ServeStats:
 
     def record_devices(self, device_stats: Dict[int, Dict[str, float]]
                        ) -> None:
-        """Snapshot a sharded store's cumulative per-device occupancy
+        """Snapshot a sharded store's cumulative per-device launches: host
+        seconds inside them and their count
         (:meth:`~repro.core.sharded.ShardedVectorStore.device_stats`)."""
         for slot, rec in device_stats.items():
             self.device_busy_s[slot] = float(rec["busy_s"])
@@ -718,14 +721,20 @@ class MicroBatchScheduler:
         Only the search itself leaves the event loop (executor thread);
         every ``stats`` mutation happens back on the loop, so overlapping
         flushes never race on accounting."""
-        st = self.stats
         error: Optional[Exception] = None
         results: List = []
         try:
             qlist = [r.query for r in batch]
-            loop = asyncio.get_running_loop()
-            results = await loop.run_in_executor(
-                None, lambda: self._search(qlist))
+
+            def flush():
+                # the root span of everything this flush does, opened on
+                # the executor thread that runs it
+                handoff_ms = (self._clock() - batch[0].t_dispatch) * 1e3
+                with obs.span("serve.flush", flush=fid, batch=len(batch),
+                              reason=reason, handoff_ms=handoff_ms):
+                    return self._search(qlist)
+            results = await asyncio.get_running_loop().run_in_executor(
+                None, flush)
         except Exception as e:         # propagate to callers, keep serving
             error = e
         finally:
@@ -733,6 +742,15 @@ class MicroBatchScheduler:
             self._inflight_slots.pop(fid, None)
             if self._slot_free is not None:
                 self._slot_free.set()
+        with obs.span("serve.resolve", batch=len(batch)):
+            self._resolve(batch, reason, results, error)
+        self._signal_idle()
+
+    def _resolve(self, batch: List[_Request], reason: str, results: List,
+                 error: Optional[Exception]) -> None:
+        """Account a finished micro-batch and resolve its futures (on the
+        loop)."""
+        st = self.stats
         # the batch was dequeued either way: flush counts stay honest
         t1 = self._clock()
         st.batches_flushed += 1
@@ -779,7 +797,6 @@ class MicroBatchScheduler:
                                      efs=r.query.efs,
                                      pwords=self._query_pwords(r.query))
                 r.future.set_result(results[i])
-        self._signal_idle()
 
 
 RequestLike = Union[Query, Tuple[np.ndarray, int, int]]
