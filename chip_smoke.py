@@ -446,7 +446,7 @@ def run_wide(sizes: Sizes, seed: int, rng, compiles, clock) -> bool:
 # ------------------------------------------------------------ sharded run
 class LaunchPlacement:
     """Wraps the kernel wrapper the device shards call and records, for each
-    launch, the devices of its node operand and of its outputs."""
+    launch, the devices of its node rows and of its outputs."""
 
     def __init__(self):
         import repro.kernels.l2_topk as pkg
@@ -455,9 +455,9 @@ class LaunchPlacement:
         self.launches: List = []
 
     def __enter__(self):
-        def recording(queries, db, *args, **kw):
-            d, i = self.inner(queries, db, *args, **kw)
-            self.launches.append((frozenset(db.devices()),
+        def recording(queries, node, *args, **kw):
+            d, i = self.inner(queries, node, *args, **kw)
+            self.launches.append((frozenset(node.db.devices()),
                                   frozenset(d.devices()) | i.devices()))
             return d, i
         self.pkg.l2_topk = recording
@@ -487,7 +487,7 @@ def run_sharded(sizes: Sizes, seed: int, chips: int) -> bool:
         shards = list(sharded.device_shards())
         devices = {s.device for s in shards}
         misplaced = [s.key for s in shards for a in
-                     (s._data_dev, s._auth_dev, s._attr_dev)
+                     (s.node.db, s.node.auth, s.node.attr)
                      if a is not None and a.devices() != {s.device}]
         log(f"[sharded] {mesh.describe()}: {len(shards)} shards on "
             f"{len(devices)} devices, imbalance "

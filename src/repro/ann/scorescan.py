@@ -8,6 +8,12 @@ query ``q`` the triangle inequality gives ``dist(q, v) >= (|q-c| - rho)^2``
 for all members, so a node whose lower bound exceeds the global k-th
 distance is skipped without touching HBM.
 
+A node's kernel operands live on the device: the first launch sends its
+centered rows, auth words and attribute words once, padded and laid out
+for the kernel (``kernels/l2_topk/ops.py::prepare_node``, span
+``scan.upload``), and every later launch sends only its queries, role
+masks and bounds.
+
 The backend picks the kernel mode: compiled on TPU, interpreted on CPU
 (see ``kernels/l2_topk/ops.py``).
 """
@@ -19,7 +25,8 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from .. import obs
-from ..kernels.l2_topk import l2_topk, L2TopKConfig
+from ..kernels.l2_topk import (L2TopKConfig, NodeOperands, l2_topk,
+                               prepare_node)
 
 
 @dataclasses.dataclass
@@ -32,6 +39,12 @@ class ScoreScanIndex:
     DESIGN.md §Role Masks).  Role-mask operands to the search methods carry
     the matching width: a scalar / ``(B,)`` for single-word indexes, a
     ``(W,)`` / ``(B, W)`` word array otherwise.
+
+    The host arrays are the index's record.  The kernel reads a device copy
+    of them in its own layout (:meth:`operands`), built at the first launch
+    and kept until :meth:`set_auth_words` changes a row's words.  Write
+    auth words through that method: an in-place write to ``auth_bits``
+    would leave the device copy stale.
     """
 
     data: np.ndarray                 # (n, d) float32
@@ -39,6 +52,8 @@ class ScoreScanIndex:
     auth_bits: np.ndarray            # (n,) or (n, W) uint32 role mask words
     config: L2TopKConfig = dataclasses.field(default_factory=L2TopKConfig)
     attr_bits: Optional[np.ndarray] = None   # (n, P) uint32 predicate words
+    _operands: Optional[NodeOperands] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     def __post_init__(self):
         self.data = np.ascontiguousarray(self.data, dtype=np.float32)
@@ -91,17 +106,31 @@ class ScoreScanIndex:
         dc = np.linalg.norm(qs - self.centroid, axis=1)
         return np.maximum(0.0, dc - self.radius) ** 2
 
+    # ------------------------------------------------------- device operands
+    def operands(self) -> NodeOperands:
+        """This node's kernel operands on the device, sent on first use."""
+        if self._operands is None:
+            self._operands = prepare_node(self._centered, self.auth_bits,
+                                          self.attr_bits, self.config)
+        return self._operands
+
+    def set_auth_words(self, vid: int, words) -> None:
+        """Rewrite the auth words of external id ``vid``'s row in place (a
+        grant or revoke that keeps the row in this node).  The device
+        operands are dropped, and sent again at the next launch."""
+        self.auth_bits[self.ids == np.int64(vid)] = words
+        self._operands = None
+
     # ---------------------------------------------------------------- search
     def _pred_kwargs(self, require, forbid):
-        """Kernel predicate operands for a require/forbid pair; empty when no
+        """Kernel predicate rows for a require/forbid pair; empty when no
         predicate is active (the exact P=0 kernel path)."""
         if require is None and forbid is None:
             return {}
         if self.attr_bits is None:
             raise ValueError(
                 "predicate filter on an index with no attr_bits plane")
-        return dict(attr_bits=self.attr_bits,
-                    require=None if require is None
+        return dict(require=None if require is None
                     else np.asarray(require, np.uint32),
                     forbid=None if forbid is None
                     else np.asarray(forbid, np.uint32))
@@ -118,11 +147,12 @@ class ScoreScanIndex:
         """
         if not len(self.data):
             return []
+        pkw = self._pred_kwargs(require, forbid)
+        node = self.operands()
         qc = (q - self.centroid).astype(np.float32)
-        d, i = l2_topk(qc[None, :], self._centered, self.auth_bits,
+        d, i = l2_topk(qc[None, :], node, None,
                        np.asarray(role_mask, np.uint32), k, bound=bound,
-                       config=self.config,
-                       **self._pred_kwargs(require, forbid))
+                       config=self.config, **pkw)
         d = np.asarray(d)[0]
         i = np.asarray(i)[0]
         keep = i >= 0
@@ -154,12 +184,12 @@ class ScoreScanIndex:
             return (np.full((b, k), np.inf, np.float32),
                     np.full((b, k), -1, np.int64))
         pkw = self._pred_kwargs(require, forbid)
+        node = self.operands()
         with obs.span("scan.launch", n=len(self.data), b=b,
-                      w=self.mask_width,
-                      p=pkw["attr_bits"].shape[1] if pkw else 0, k=k):
+                      w=self.mask_width, p=node.p if pkw else 0, k=k):
             qc = (np.asarray(qs, np.float32) - self.centroid).astype(
                 np.float32)
-            d, i = l2_topk(qc, self._centered, self.auth_bits,
+            d, i = l2_topk(qc, node, None,
                            np.asarray(role_masks, np.uint32), k,
                            bound=None if bounds is None
                            else np.asarray(bounds, np.float32),

@@ -41,6 +41,7 @@ from .queryplan import Plan, build_all_plans
 from .store import VectorStore
 from .costmodel import HNSWCostModel
 from ..ann.exact import ExactIndex
+from ..ann.scorescan import ScoreScanIndex
 
 
 class DynamicStore:
@@ -414,7 +415,10 @@ class DynamicStore:
             elif vid in set(int(i) for i in eng.ids):
                 # old and new block share this container: refresh the row's
                 # auth words in place so the in-kernel filter tracks new_tau
-                if isinstance(eng, MaskedEngine):
+                # (a ScoreScan node also drops its stale device copy)
+                if isinstance(eng, ScoreScanIndex):
+                    eng.set_auth_words(vid, self._auth_row(eng, new_tau))
+                elif isinstance(eng, MaskedEngine):
                     eng.auth_bits[eng.ids == np.int64(vid)] = \
                         self._auth_row(eng, new_tau)
             else:

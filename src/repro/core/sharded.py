@@ -16,10 +16,12 @@ Pieces:
     estimate; any node larger than a row threshold is split row-wise into
     per-device :class:`DeviceShard` slices first.
   * :class:`DeviceShard` — one device-pinned, contiguous row slice of a
-    node's ScoreScan data (``jax.device_put``-committed centered rows and
-    ``(N, W)`` auth words), scoring queries with the same kernel call —
-    and bit-identical distances — as the parent
-    :class:`~repro.ann.scorescan.ScoreScanIndex`.
+    node's ScoreScan data (centered rows, auth and attribute words laid
+    out for the kernel and committed to the shard's device by
+    ``l2_topk.prepare_node``, as the parent
+    :class:`~repro.ann.scorescan.ScoreScanIndex` keeps its own), scoring
+    queries with the same kernel call — and bit-identical distances — as
+    the parent.
   * :class:`ShardedVectorStore` — the drop-in store wrapper: the same
     ``search(queries)`` entry point, executed as per-device waves.  One
     single-worker executor per mesh slot acts as that device's launch
@@ -188,7 +190,7 @@ class DeviceShard:
 
     def __init__(self, parent, device, slot: int, lo: int, hi: int,
                  key: object = None):
-        from ..launch.sharding import pin_rows
+        from ..kernels.l2_topk import prepare_node
         self.key = key
         self.slot = int(slot)
         self.device = device
@@ -204,13 +206,12 @@ class DeviceShard:
         if len(rows):
             norms2 = (rows * rows).sum(axis=1)
             self.radius = float(np.sqrt(norms2.max()))
-            self._data_dev, self._auth_dev = pin_rows(
-                [rows, parent.auth_bits[lo:hi]], device)
-            self._attr_dev = None if attr is None else pin_rows(
-                [attr[lo:hi]], device)[0]
+            self.node = prepare_node(
+                rows, parent.auth_bits[lo:hi],
+                None if attr is None else attr[lo:hi], self.config, device)
         else:
             self.radius = 0.0
-            self._data_dev = self._auth_dev = self._attr_dev = None
+            self.node = None
 
     def __len__(self) -> int:
         return self.hi - self.lo
@@ -252,16 +253,15 @@ class DeviceShard:
             np.asarray(bounds, np.float32), self.device)
         pkw = {}
         if require is not None or forbid is not None:
-            if self._attr_dev is None:
+            if self.node.attr is None:
                 raise ValueError(
                     "predicate rows against a shard with no attr plane")
             pkw = dict(
-                attr_bits=self._attr_dev,
                 require=jax.device_put(np.asarray(require, np.uint32),
                                        self.device),
                 forbid=jax.device_put(np.asarray(forbid, np.uint32),
                                       self.device))
-        d, i = l2_topk(qd, self._data_dev, self._auth_dev, md, k,
+        d, i = l2_topk(qd, self.node, None, md, k,
                        bound=bd, config=self.config, **pkw)
         return read_back(d, i, self.ids)
 

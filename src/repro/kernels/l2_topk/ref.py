@@ -41,35 +41,45 @@ def _per_query(x, dtype) -> jax.Array:
     return x[:, None]                              # broadcasts over (B, N)
 
 
+def as_words(x) -> jax.Array:
+    """Packed uint32 words as ``(N, W)``: a ``(N,)`` single-word array
+    becomes one column."""
+    x = jnp.asarray(x, jnp.uint32)
+    return x[:, None] if x.ndim == 1 else x
+
+
 def normalize_masks(auth_bits, role_mask):
     """Common (N, W) auth / (·, W) role-mask normalization for ref + ops.
 
     Returns ``(auth (N, W) uint32, mask (B'|1, W) uint32, W)``.  Single-word
     operands keep their legacy forms: ``(N,)`` auth with a scalar or ``(B,)``
-    mask.  For ``W > 1`` the mask must carry all W words — ``(W,)`` shared or
-    ``(B, W)`` per query; a bare scalar/(B,) would silently drop roles >= 32,
-    so it is rejected.
+    mask.  For ``W > 1`` the mask must carry all W words (see
+    :func:`normalize_role_mask`).
     """
-    auth = jnp.asarray(auth_bits, jnp.uint32)
-    if auth.ndim == 1:
-        auth = auth[:, None]                                     # (N, 1)
+    auth = as_words(auth_bits)
     w = auth.shape[1]
+    return auth, normalize_role_mask(role_mask, w), w
+
+
+def normalize_role_mask(role_mask, w: int) -> jax.Array:
+    """A role mask for ``w``-word auth masks as ``(B'|1, W)`` uint32.  For
+    ``w > 1`` it must carry all ``w`` words — ``(W,)`` shared or ``(B, W)``
+    per query; a bare scalar/(B,) would silently drop roles >= 32, so it is
+    rejected."""
     mask = jnp.asarray(role_mask, jnp.uint32)
     if w == 1:
-        mask = mask.reshape(-1)[:, None]                         # (B'|1, 1)
-    elif mask.ndim == 1:
+        return mask.reshape(-1)[:, None]                         # (B'|1, 1)
+    if mask.ndim == 1:
         if mask.shape[0] != w:
             raise ValueError(
                 f"role_mask must carry all {w} mask words: got shape "
                 f"{mask.shape} (per-query masks are (B, {w}))")
-        mask = mask[None, :]                                     # (1, W)
-    elif mask.ndim == 2 and mask.shape[1] == w:
-        pass                                                     # (B, W)
-    else:
-        raise ValueError(
-            f"role_mask shape {mask.shape} incompatible with {w}-word "
-            f"auth masks")
-    return auth, mask, w
+        return mask[None, :]                                     # (1, W)
+    if mask.ndim == 2 and mask.shape[1] == w:
+        return mask                                              # (B, W)
+    raise ValueError(
+        f"role_mask shape {mask.shape} incompatible with {w}-word "
+        f"auth masks")
 
 
 def normalize_predicates(attr_bits, require, forbid):
@@ -77,20 +87,24 @@ def normalize_predicates(attr_bits, require, forbid):
 
     Returns ``(attr (N, P), require (B'|1, P), forbid (B'|1, P), P)`` as
     uint32, or ``None`` when ``attr_bits`` is None (the unfiltered path).
-    ``require``/``forbid`` may be ``None`` (all-zero: no constraint on that
-    side), ``(P,)`` shared, or ``(B, P)`` per query — like role masks, a row
-    that drops words would silently pass bits past word 0, so short rows are
-    rejected.
+    ``require``/``forbid`` follow :func:`predicate_rows`.
     """
     if attr_bits is None:
         if require is not None or forbid is not None:
             raise ValueError(
                 "require/forbid word rows need (N, P) attr_bits to filter on")
         return None
-    attr = jnp.asarray(attr_bits, jnp.uint32)
-    if attr.ndim == 1:
-        attr = attr[:, None]                                     # (N, 1)
+    attr = as_words(attr_bits)
     p = attr.shape[1]
+    return (attr, *predicate_rows(require, forbid, p), p)
+
+
+def predicate_rows(require, forbid, p: int):
+    """``(require, forbid)`` for a ``p``-word attr plane as ``(B'|1, P)``
+    uint32 rows.  Each may be ``None`` (all-zero: no constraint on that
+    side), ``(P,)`` shared, or ``(B, P)`` per query — like role masks, a row
+    that drops words would silently pass bits past word 0, so short rows are
+    rejected."""
 
     def _rows(x, name):
         if x is None:
@@ -109,7 +123,7 @@ def normalize_predicates(attr_bits, require, forbid):
         raise ValueError(
             f"{name} shape {x.shape} incompatible with {p}-word attr plane")
 
-    return attr, _rows(require, "require"), _rows(forbid, "forbid"), p
+    return _rows(require, "require"), _rows(forbid, "forbid")
 
 
 def l2_topk_ref(queries: jax.Array, db: jax.Array, auth_bits: jax.Array,

@@ -9,16 +9,16 @@ how e.g. minicpm's 36 heads degrade gracefully on a 16-way model axis.
 
 Retrieval sharding is much simpler than the training rules: lattice nodes
 are disjoint, so a node shard is just a contiguous row range pinned to one
-device (:func:`pin_rows`), and row-splitting a node across devices is an
-even partition of its row count (:func:`even_row_splits`) — no named axes,
-no collectives (DESIGN.md §Sharded Execution)."""
+device (``l2_topk.prepare_node`` with that device), and row-splitting a
+node across devices is an even partition of its row count
+(:func:`even_row_splits`) — no named axes, no collectives (DESIGN.md
+§Sharded Execution)."""
 from __future__ import annotations
 
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
@@ -155,17 +155,6 @@ def even_row_splits(n: int, parts: int) -> List[Tuple[int, int]]:
             out.append((lo, hi))
         lo = hi
     return out
-
-
-def pin_rows(arrays: Sequence[np.ndarray], device) -> Tuple[jax.Array, ...]:
-    """Commit host arrays to ``device`` (``jax.device_put``).
-
-    Committed operands make every jit launch that consumes them execute on
-    that device — the pinning step behind each
-    :class:`~repro.core.sharded.DeviceShard`.  Returns jax arrays in input
-    order."""
-    return tuple(jax.device_put(np.ascontiguousarray(a), device)
-                 for a in arrays)
 
 
 def tree_shardings(rules: Rules, axes_tree):

@@ -91,13 +91,10 @@ def test_correctness_after_mixed_churn(dyn, small_policy):
 
 
 # ------------------------------------------------- unified API + satellites
-@pytest.fixture()
-def scan_dyn():
-    """ScoreScan-engine dynamic store: mutations rebuild MaskedEngines with
-    fresh auth bits and queries take the batched kernel path."""
+def _scan_dyn(seed):
     from repro.ann.scorescan import scorescan_factory
     policy = generate_policy(n_vectors=1200, n_roles=8, n_permissions=20,
-                             seed=3)
+                             seed=seed)
     rng = np.random.default_rng(4)
     vecs = rng.standard_normal((policy.n_vectors, 16)).astype(np.float32)
     cm = HNSWCostModel(lam_threshold=100)
@@ -105,6 +102,20 @@ def scan_dyn():
     store = build_vector_storage(res, vecs,
                                  engine_factory=scorescan_factory(policy))
     return DynamicStore(store, cm)
+
+
+@pytest.fixture()
+def scan_dyn():
+    """ScoreScan-engine dynamic store: mutations rebuild MaskedEngines with
+    fresh auth bits and queries take the batched kernel path."""
+    return _scan_dyn(3)
+
+
+@pytest.fixture()
+def scan_dyn_shared():
+    """``scan_dyn`` built from a policy whose nodes each hold blocks one
+    grant apart, so a grant or revoke can keep a row in its node."""
+    return _scan_dyn(5)
 
 
 def test_scan_store_mutations_through_store_search(scan_dyn):
@@ -186,6 +197,64 @@ def test_scan_store_grant_revoke_churn_parity(scan_dyn):
         x = rng.standard_normal(16).astype(np.float32)
         got = [i for _, i in dyn.search(x, r, k=8)]
         assert got == _truth(dyn, x, r, 8)[:len(got)], r
+
+
+def test_in_place_auth_refresh_reaches_the_device_operands(
+        scan_dyn_shared):
+    """A grant or revoke that keeps a row in a ScoreScan node rewrites the
+    row's auth words in place, in the same engine.  That engine's device
+    operands, built by an earlier search, must follow before the next
+    launch: a launch of the node under the granted role finds the row, one
+    after the revoke does not, and every answer matches the exact oracle
+    with no unauthorized hit."""
+    dyn = scan_dyn_shared
+    policy = dyn.store.policy
+    pick = None
+    for vid in sorted(dyn.vec_block):
+        b = dyn.vec_block[vid]
+        tau = dyn.block_roles[b]
+        old_nodes = set(dyn._containers(b)[0])
+        for r in range(policy.n_roles):
+            new_tau = frozenset(tau | {r})
+            if r in tau or new_tau not in dyn.block_roles:
+                continue
+            nb = dyn.block_roles.index(new_tau)
+            shared = old_nodes & set(dyn._containers(nb)[0])
+            if shared:
+                pick = (vid, r, tau, sorted(shared, key=str))
+                break
+        if pick:
+            break
+    assert pick is not None
+    vid, r, tau, shared = pick
+    x = dyn.store.data[vid]
+    for role in range(policy.n_roles):     # every node uploads its operands
+        dyn.search(x, role, k=8)
+    engines = [dyn.store.engines[key] for key in shared]
+    assert all(e._operands is not None for e in engines)
+    mask_r = dyn.store.kernel_role_mask((r,))
+
+    def check(role, k=8):
+        got = [i for _, i in dyn.search(x, role, k=k)]
+        assert got == _truth(dyn, x, role, k)[:len(got)], role
+        assert dyn.store.authorized_mask(role)[got].all(), role
+        return got
+
+    dyn.grant(vid, r)
+    # the row stayed in these engines: the in-place path, not a rebuild
+    assert [dyn.store.engines[key] for key in shared] == engines
+    for eng in engines:
+        assert eng.search_masked(x, 1, mask_r)[0][1] == vid
+    assert check(r)[0] == vid
+    for role in tau:
+        assert check(role)[0] == vid
+    dyn.revoke(vid, r)
+    assert [dyn.store.engines[key] for key in shared] == engines
+    for eng in engines:
+        assert vid not in [i for _, i in eng.search_masked(x, 8, mask_r)]
+    assert vid not in check(r)
+    for role in tau:
+        assert check(role)[0] == vid
 
 
 def test_unseen_role_combination_makes_fresh_leftover_block(scan_dyn):

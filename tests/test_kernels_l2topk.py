@@ -1,9 +1,12 @@
 """Pallas l2_topk kernel vs pure-jnp oracle: shape/dtype/bound sweeps."""
+import itertools
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.l2_topk import l2_topk, l2_topk_ref, L2TopKConfig
+from repro.kernels.l2_topk import (l2_topk, l2_topk_ref, L2TopKConfig,
+                                   prepare_node)
 
 
 def _case(B, N, d, k, seed=0, role_bit=3, bound=None, cfg=None):
@@ -549,3 +552,53 @@ def test_importing_repro_allocates_no_device_array():
                        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]
     assert int(r.stdout.split()[-1]) > 50
+
+
+@pytest.mark.parametrize("W,P,N,B", list(itertools.product(
+    (1, 2), (0, 1), (300, 512), (1, 5, 8))))
+def test_resident_operands_match_host_arrays(W, P, N, B):
+    """Node operands laid out once on the device (``prepare_node``) give
+    the kernel the values the host-array path builds at every launch:
+    bit-identical (dists, ids), from ``l2_topk`` and from a
+    ``ScoreScanIndex`` before and after it holds its bundle."""
+    from repro.ann.scorescan import ScoreScanIndex, read_back
+    d, k = 24, 6
+    rng = np.random.default_rng(1000 * W + 100 * P + N + B)
+    db = (rng.standard_normal((N, d)) + 3.0).astype(np.float32)
+    q = (db[rng.integers(N, size=B)]
+         + 0.3 * rng.standard_normal((B, d))).astype(np.float32)
+    # each row holds one of 4 roles per word: about a quarter authorized
+    auth = np.uint32(1) << rng.integers(0, 4, size=(N, W), dtype=np.uint32)
+    masks = np.uint32(1) << rng.integers(0, 4, size=(B, W), dtype=np.uint32)
+    if W == 1:
+        auth, masks = auth[:, 0], masks[:, 0]
+    bounds = np.where(np.arange(B) % 2 == 0, np.inf,
+                      2.0 * d).astype(np.float32)
+    pred, attr = {}, None
+    if P:
+        attr = rng.integers(0, 2 ** 8, size=(N, P)).astype(np.uint32)
+        pred = dict(require=np.full((B, P), 1, np.uint32),
+                    forbid=np.full((B, P), 2, np.uint32))
+
+    host = l2_topk(q, db, auth, masks, k, bound=bounds, attr_bits=attr,
+                   **pred)
+    node = prepare_node(db, auth, attr)
+    assert (node.n, node.w, node.p) == (N, W, P)
+    resident = l2_topk(q, node, None, masks, k, bound=bounds, **pred)
+    for a, b in zip(host, resident):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (np.asarray(host[1]) >= 0).any()
+
+    idx = ScoreScanIndex(data=db, ids=np.arange(N, dtype=np.int64) * 3 + 1,
+                         auth_bits=auth, attr_bits=attr)
+    qc = (q - idx.centroid).astype(np.float32)
+    want = read_back(*l2_topk(qc, idx._centered, idx.auth_bits, masks, k,
+                              bound=bounds, attr_bits=attr, **pred),
+                     idx.ids)
+    assert idx._operands is None
+    first = idx.search_masked_batch(q, k, masks, bounds, **pred)
+    assert idx._operands is not None
+    second = idx.search_masked_batch(q, k, masks, bounds, **pred)
+    for got in (first, second):
+        for a, b in zip(want, got):
+            np.testing.assert_array_equal(a, b)

@@ -1,8 +1,8 @@
 """The span-and-counter recorder (``repro.obs``): nesting and roots, the
 spans of a served flush and their per-flush sums, the launches of a
-sharded store in their flush's tree, the host-to-device byte counter,
-compile counts on the step that compiled, and the bounds of what the
-recorder holds."""
+sharded store in their flush's tree, the host-to-device byte counter and
+the node operands that stay on the device, compile counts on the step that
+compiled, and the bounds of what the recorder holds."""
 import asyncio
 import time
 
@@ -14,9 +14,10 @@ from repro import obs
 from repro.ann.scorescan import scorescan_factory
 from repro.core import (HNSWCostModel, Query, build_effveda,
                         build_vector_storage, generate_policy, shard_store)
-from repro.kernels.l2_topk import l2_topk
+from repro.kernels.l2_topk import l2_topk, prepare_node
 from repro.launch.mesh import DeviceMesh
 from repro.launch.scheduler import MicroBatchScheduler, serve_requests
+from repro.launch.serve import warm_batch_shapes
 
 
 def _store():
@@ -136,15 +137,22 @@ def test_every_span_of_a_flush_carries_its_flush_root(store):
     assert trees[0]["name"] == "serve.flush" and trees[0]["children"]
 
 
-@pytest.mark.parametrize("db_on_device", [False, True],
-                         ids=["host-db", "device-db"])
-def test_h2d_bytes_counts_host_operands_only(db_on_device):
+@pytest.mark.parametrize("node", ["host", "device", "resident"],
+                         ids=["host-db", "device-db", "resident"])
+def test_h2d_bytes_counts_host_operands_only(node):
     q, db, auth, masks, bounds = _one_launch()
-    host = q.nbytes + auth.nbytes + masks.nbytes + bounds.nbytes
-    if db_on_device:
+    host = q.nbytes + masks.nbytes + bounds.nbytes
+    if node == "resident":
+        t = time.perf_counter()
+        db, auth = prepare_node(db, auth), None
+        # the rows and auth words cross once, in their own span
+        (up,) = _named(obs.spans(since=t), "scan.upload")
+        assert up.attrs["upload_bytes"] == 4 * 300 * (16 + 1)
+    elif node == "device":
         db = jnp.asarray(db)
+        host += auth.nbytes
     else:
-        host += db.nbytes
+        host += db.nbytes + auth.nbytes
     l2_topk(q, db, auth, masks, 4, bound=bounds)      # compile outside
     t = time.perf_counter()
     before = obs.counters().get("h2d_bytes", 0)
@@ -153,9 +161,31 @@ def test_h2d_bytes_counts_host_operands_only(db_on_device):
     assert prep.attrs["h2d_bytes"] == host
     assert obs.counters()["h2d_bytes"] - before == host
     assert prep.attrs["launches"] == 1
+    assert prep.attrs.get("resident_launches", 0) == (node == "resident")
     assert prep.attrs["rows_scanned"] == 300
     # 300 rows padded to 512, 5 query rows padded to 8
     assert prep.attrs["rows_padded"] == 212 + 3
+
+
+def test_served_flushes_launch_resident_node_operands(store):
+    warm_batch_shapes(store, sizes=(8,))      # every engine uploads here
+    t = time.perf_counter()
+    results = _serve(store, _queries(store, 24, 12))
+    assert len(results) == 24
+    got = obs.spans(since=t)
+    assert not _named(got, "scan.upload")
+    roots = [r for r in obs.roots(since=t) if r.span.name == "serve.flush"]
+    assert roots
+    for r in roots:
+        assert r.counts["resident_launches"] == r.counts["launches"] > 0
+        assert "upload_bytes" not in r.counts
+    # a launch sends its queries, role masks and bounds, no node row
+    d = store.data.shape[1]
+    for launch in _named(got, "scan.launch"):
+        (prep,) = [s for s in got
+                   if s.parent == launch.id and s.name == "l2_topk.prep"]
+        b = launch.attrs["b"]
+        assert prep.attrs["h2d_bytes"] in (4 * b * (d + 1), 4 * b * (d + 2))
 
 
 def test_first_call_at_a_new_shape_compiles_on_dispatch():
