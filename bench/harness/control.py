@@ -27,15 +27,15 @@ def _bf16(x: np.ndarray) -> np.ndarray:
 
 
 def bf16_answers(ref: Reference, queries: Sequence) -> List[Answer]:
-    """Each query's top-k by one-pass bfloat16 distances over its allowed
-    rows, ties to the smaller id."""
+    """Each query's top-k by one-pass bfloat16 distances over the rows it
+    may read, ties to the smaller id."""
     rounded = _bf16(ref.vectors)
     out = []
     for q in queries:
         qv = np.asarray(q.vector, np.float32)
         qn = np.float32(qv @ qv)
         qr = _bf16(qv)
-        ids = np.flatnonzero(ref.mask(q.roles))
+        ids = np.flatnonzero(ref.mask(q.roles, q.where))
         best_d = np.empty(0, np.float32)
         best_i = np.empty(0, np.int64)
         for lo in range(0, len(ids), CONTROL_ROWS):

@@ -12,7 +12,18 @@ program cannot change what it is measured on:
   so every run seed serves the same lattice, node sizes and compiled shapes,
   over different vectors and traffic;
 - query vectors as the section 7.1 generator draws them: a point of the
-  querying role's own data plus Gaussian noise.
+  querying role's own data plus Gaussian noise;
+- where the configuration declares ``predicates``, each row's raw
+  attributes (a value index per tag field, drawn by the field's Zipf
+  weights; a float per range field, uniform on its range), and where the
+  traffic mix declares ``filtered_share``, a ``where`` clause on each query
+  with that probability, drawn from the mix's ``where_pool`` of templates.
+
+Each draw of a run seed has a numpy stream of its own (``host_rng``): 3
+the queries, 5 the sample that is checked, 6 the queries' clauses, 7 the
+rows' attributes; the vectors are drawn on the device.  A configuration
+without ``predicates`` and a mix without ``filtered_share`` draw exactly
+what they drew before the predicate plane.
 
 Nothing in this module imports the program.
 """
@@ -20,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, FrozenSet, List, Sequence, Tuple
+from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -160,6 +171,60 @@ def copy_into(out: np.ndarray, at: int, block) -> None:
     out[at:at + n] = np.asarray(block)[:n]
 
 
+# --------------------------------------------------------------- attributes
+Atom = Tuple[str, str, object]     # (op, field, tag index | range edge)
+TAG_OPS = ("has", "lacks")
+RANGE_OPS = ("ge", "lt")
+
+
+@dataclasses.dataclass(frozen=True)
+class Attributes:
+    """Each row's raw attribute values: a value index per tag field and a
+    float per range field, under the configuration's ``predicates``."""
+
+    n_rows: int
+    tags: Dict[str, np.ndarray]         # field -> (N,) value index
+    ranges: Dict[str, np.ndarray]       # field -> (N,) float64 value
+
+    def eligible(self, where: Sequence[Atom]) -> np.ndarray:
+        """(N,) bool: the rows that satisfy every atom of ``where``:
+        ``has``/``lacks`` a tag value, ``ge``/``lt`` a range edge."""
+        ok = np.ones(self.n_rows, bool)
+        for op, field, value in where:
+            if op == "has":
+                ok &= self.tags[field] == value
+            elif op == "lacks":
+                ok &= self.tags[field] != value
+            elif op == "ge":
+                ok &= self.ranges[field] >= value
+            elif op == "lt":
+                ok &= self.ranges[field] < value
+            else:
+                raise ValueError(f"unknown where op {op!r}")
+        return ok
+
+
+def tag_weights(spec: Dict) -> np.ndarray:
+    """A tag field's value weights: Zipf with exponent ``zipf`` over
+    ``values`` values, value 0 the most frequent."""
+    w = np.arange(1, spec["values"] + 1, dtype=np.float64) ** -spec["zipf"]
+    return w / w.sum()
+
+
+def draw_attributes(seed: int, n_vectors: int, predicates: Dict
+                    ) -> Attributes:
+    """The rows' raw attributes under ``predicates`` (``{"tags": {field:
+    {"values", "zipf"}}, "ranges": {field: {"low", "high", "edges"}}}``),
+    fields in declaration order, on stream 7 of the run seed."""
+    rng = host_rng(seed, 7)
+    tags = {f: rng.choice(spec["values"], size=n_vectors,
+                          p=tag_weights(spec)).astype(np.int64)
+            for f, spec in predicates.get("tags", {}).items()}
+    ranges = {f: rng.uniform(spec["low"], spec["high"], n_vectors)
+              for f, spec in predicates.get("ranges", {}).items()}
+    return Attributes(n_rows=n_vectors, tags=tags, ranges=ranges)
+
+
 # ------------------------------------------------------------------ queries
 @dataclasses.dataclass(frozen=True)
 class QuerySpec:
@@ -169,6 +234,7 @@ class QuerySpec:
     vector: np.ndarray
     roles: Tuple[int, ...]
     k: int
+    where: Optional[Tuple[Atom, ...]] = None
 
 
 def draw_queries(seed: int, n: int, vectors: np.ndarray, policy: PolicyDraw,
@@ -195,3 +261,102 @@ def draw_queries(seed: int, n: int, vectors: np.ndarray, policy: PolicyDraw,
         out.append(QuerySpec(vector=vec, roles=roles, k=k))
     return out
 
+
+def fill_clause(template: Sequence, predicates: Dict,
+                rng: np.random.Generator) -> Tuple[Atom, ...]:
+    """One clause from a template of ``[op, field, placeholder]`` atoms: a
+    tag atom takes a value drawn by its field's weights; the range atoms
+    of one field take distinct edges, ascending in atom order, drawn
+    uniformly from the edges above the field's ``low`` (``ge low`` filters
+    nothing and ``lt low`` admits nothing)."""
+    values: Dict[int, object] = {}
+    by_field: Dict[str, List[int]] = {}
+    for j, (op, field, _) in enumerate(template):
+        if op in TAG_OPS:
+            spec = predicates["tags"][field]
+            values[j] = int(rng.choice(spec["values"], p=tag_weights(spec)))
+        elif op in RANGE_OPS:
+            by_field.setdefault(field, []).append(j)
+        else:
+            raise ValueError(f"unknown where op {op!r} in {template!r}")
+    for field, atoms in by_field.items():
+        spec = predicates["ranges"][field]
+        edges = [float(e) for e in spec["edges"] if e > spec["low"]]
+        pick = np.sort(rng.choice(len(edges), len(atoms), replace=False))
+        for j, e in zip(atoms, pick):
+            values[j] = edges[e]
+    return tuple((op, field, values[j])
+                 for j, (op, field, _) in enumerate(template))
+
+
+def add_filters(seed: int, pool: List[QuerySpec], share: float,
+                templates: Sequence, predicates: Dict) -> List[QuerySpec]:
+    """Each query takes a clause with probability ``share``, one coin per
+    query; the clause comes from one of the ``templates``, drawn with equal
+    weights.  All on stream 6 of the run seed."""
+    rng = host_rng(seed, 6)
+    out = []
+    for q in pool:
+        if rng.random() < share:
+            t = templates[int(rng.integers(len(templates)))]
+            q = dataclasses.replace(q, where=fill_clause(t, predicates, rng))
+        out.append(q)
+    return out
+
+
+# a kind of flush that the mix makes with less probability is not warmed
+NEVER = 1e-9
+
+
+def flush_kinds(share: float, batch: int) -> Tuple[bool, ...]:
+    """Whether a flush of ``batch`` queries holds a filtered query: the
+    kinds (False: none, True: at least one) that the mix makes with more
+    probability than ``NEVER``.  With one coin per query (``add_filters``)
+    a flush holds none with probability ``(1 - share) ** batch``."""
+    bare = (1.0 - share) ** batch
+    return tuple(f for f, p in ((False, bare), (True, 1.0 - bare))
+                 if p > NEVER)
+
+
+# ------------------------------------------------------------------ a cell
+@dataclasses.dataclass
+class CellData:
+    """Everything one run of a cell draws from its seed."""
+
+    policy: PolicyDraw
+    vectors: np.ndarray
+    attrs: Optional[Attributes]
+    pool: List[QuerySpec]
+
+
+def draw_cell(config: Dict, traffic: Dict, seed: int) -> CellData:
+    """The policy, the corpus, the rows' attributes (where the
+    configuration declares ``predicates``) and the query pool (filtered
+    where the mix declares ``filtered_share``)."""
+    cfg, tr = config, traffic
+    policy = draw_policy(cfg["n_vectors"], cfg["n_roles"],
+                         cfg["n_permissions"], cfg["block_zipf"],
+                         cfg["perm_zipf"], cfg["max_roles_per_perm"],
+                         cfg["policy_seed"])
+    vectors = draw_vectors(seed, cfg["n_vectors"], cfg["dim"],
+                           cfg["n_clusters"], cfg["center_scale"])
+    attrs = None
+    if "predicates" in cfg:
+        attrs = draw_attributes(seed, cfg["n_vectors"], cfg["predicates"])
+    pool = draw_queries(seed, tr["pool"], vectors, policy, tr["k"],
+                        tr["union_share"], cfg["query_noise"])
+    if tr.get("filtered_share", 0.0):
+        if attrs is None:
+            raise ValueError("a filtered traffic mix needs a configuration "
+                             "that declares predicates")
+        pool = add_filters(seed, pool, tr["filtered_share"],
+                           tr["where_pool"], cfg["predicates"])
+    return CellData(policy=policy, vectors=vectors, attrs=attrs, pool=pool)
+
+
+def check_pick(seed: int, n_requests: int, n_sample: int) -> np.ndarray:
+    """The window requests held to the reference: ``n_sample`` of them
+    (at most all), drawn on stream 5 of the run seed, ascending."""
+    rng = host_rng(seed, 5)
+    return np.sort(rng.choice(n_requests, min(n_sample, n_requests),
+                              replace=False))

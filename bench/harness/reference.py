@@ -1,8 +1,9 @@
 """The plain reference and the comparison that decides ``correct``.
 
-The reference is a brute force over the raw corpus and the raw access
-assignment: it imports nothing of the program and takes nothing the
-program made.  It works in blocks of rows: a float32 pass over every row
+The reference is a brute force over the raw corpus, the raw access
+assignment and, for a filtered query, the rows' raw attributes: a query
+may read the rows its roles allow that satisfy its ``where`` clause.  It
+imports nothing of the program and takes nothing the program made.  It works in blocks of rows: a float32 pass over every row
 proposes, for each sampled query, the candidates that can be in its
 authorized top-k within a safe margin, and float64 arithmetic on those
 candidates decides the answer.
@@ -12,9 +13,10 @@ are compared, each with its limit:
 
 - ``missing``: sampled requests that were never answered, failed, or were
   refused (limit 0);
-- ``unauthorized``: hits the querying roles may not read (limit 0);
-- ``malformed``: answers with the wrong number of hits, a repeated id, or
-  distances out of order (limit 0);
+- ``unauthorized``: hits the query may not read: outside its roles' rows
+  or failing its clause (limit 0);
+- ``malformed``: answers with another number of hits than min(k, rows the
+  query may read), a repeated id, or distances out of order (limit 0);
 - ``dist_err``: the widest gap between a served distance and the exact
   float64 distance of the served id (limit from the configuration);
 - ``rank_gap``: the widest amount by which the exact distance of the j-th
@@ -48,19 +50,28 @@ class Answer:
 
 
 class Reference:
-    """Exact authorized top-k over the raw data."""
+    """Exact authorized (and, for a filtered query, eligible) top-k over
+    the raw data."""
 
     def __init__(self, vectors: np.ndarray,
-                 allowed: Callable[[Tuple[int, ...]], np.ndarray]):
+                 allowed: Callable[[Tuple[int, ...]], np.ndarray],
+                 eligible: Optional[Callable[[Tuple], np.ndarray]] = None):
         self.vectors = vectors
         self._allowed = allowed
+        self._eligible = eligible
         self._masks: Dict = {}
 
-    def mask(self, roles) -> np.ndarray:
+    def mask(self, roles, where=None) -> np.ndarray:
+        """(N,) bool: the rows ``roles`` allow that satisfy ``where``."""
         key = tuple(roles)
         if key not in self._masks:
             self._masks[key] = self._allowed(key)
-        return self._masks[key]
+        if not where:
+            return self._masks[key]
+        if (key, where) not in self._masks:
+            self._masks[key, where] = self._masks[key] & \
+                self._eligible(where)
+        return self._masks[key, where]
 
     def exact(self, q: np.ndarray, ids: np.ndarray) -> np.ndarray:
         """float64 squared L2 distances from ``q`` to rows ``ids``."""
@@ -70,14 +81,15 @@ class Reference:
     def topk(self, queries: Sequence, k_of: Sequence[int]
              ) -> List[Tuple[np.ndarray, np.ndarray]]:
         """True (ids, float64 distances) for each query, sorted by
-        (distance, id); ``queries`` carry ``vector`` and ``roles``."""
+        (distance, id); ``queries`` carry ``vector``, ``roles`` and
+        ``where``."""
         n, dim = self.vectors.shape
         qs = np.stack([q.vector for q in queries]).astype(np.float32)
         qn = np.einsum("bd,bd->b", qs, qs)
         keep = [max(1, k) + KEEP_EXTRA for k in k_of]
         cand_d = [np.empty(0, np.float32) for _ in queries]
         cand_i = [np.empty(0, np.int64) for _ in queries]
-        masks = [self.mask(q.roles) for q in queries]
+        masks = [self.mask(q.roles, q.where) for q in queries]
         rows = max(1024, BLOCK_ELEMENTS // dim)
         for lo in range(0, n, rows):
             v = self.vectors[lo:lo + rows]
@@ -129,7 +141,7 @@ def compare(ref: Reference, queries: Sequence, answers: Sequence[Answer],
             continue
         ids = np.asarray(a.ids, np.int64)
         dists = np.asarray(a.dists, np.float64)
-        mask = ref.mask(q.roles)
+        mask = ref.mask(q.roles, q.where)
         inside = (ids >= 0) & (ids < len(mask))
         if not inside.all() or not mask[ids].all():
             unauthorized += 1

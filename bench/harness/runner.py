@@ -68,6 +68,14 @@ def _flush_summary(spans, t0: float) -> str:
             f"longest={longest.seconds:.3f} at +{longest.t0 - t0:.1f} s")
 
 
+def _filter_summary(spans) -> str:
+    s = [f for f in spans if f.name == FLUSH]
+    mixed = sum(0 < f.filtered < f.rows for f in s)
+    return (f"filtered: {sum(f.filtered for f in s)} of "
+            f"{sum(f.rows for f in s)} queries; {mixed} of {len(s)} flushes "
+            f"held filtered and unfiltered queries")
+
+
 def _check_lines(checks: Dict[str, Dict[str, float]]) -> str:
     return "\n".join(f"check {n}: {c['value']!r} (limit {c['limit']!r})"
                      for n, c in checks.items())
@@ -99,18 +107,12 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     compiles = CompileCounter()
 
     t = time.perf_counter()
-    draw = corpus.draw_policy(cfg["n_vectors"], cfg["n_roles"],
-                              cfg["n_permissions"], cfg["block_zipf"],
-                              cfg["perm_zipf"], cfg["max_roles_per_perm"],
-                              cfg["policy_seed"])
-    vectors = corpus.draw_vectors(seed, cfg["n_vectors"], cfg["dim"],
-                                  cfg["n_clusters"], cfg["center_scale"])
-    pool = corpus.draw_queries(seed, tr["pool"], vectors, draw, tr["k"],
-                               tr["union_share"], cfg["query_noise"])
+    data = corpus.draw_cell(cfg, tr, seed)
+    pool = data.pool
     t_data = time.perf_counter() - t
 
     t = time.perf_counter()
-    built = system.build(cfg, vectors, draw)
+    built = system.build(cfg, data.vectors, data.policy, data.attrs)
     queries = [system.to_query(q) for q in pool]
     rec = SpanRecorder()
     system.instrument(built, rec)
@@ -119,7 +121,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
     t = time.perf_counter()
     snap = compiles.snapshot()
-    calls = system.warm(built, tr["k"], tr["max_batch"], cfg["dim"], seed)
+    calls = system.warm(built, tr["k"], tr["max_batch"], cfg["dim"], seed,
+                        corpus.flush_kinds(tr.get("filtered_share", 0.0),
+                                           tr["max_batch"]))
     log(f"warm-up shapes: {calls} calls, {compiles.since(snap)}")
     snap = compiles.snapshot()
 
@@ -167,6 +171,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         f"queue_depth_peak={stats.queue_depth_peak} paths={stats.paths}; "
         f"window {compiles.since(snap)}")
     log(f"window {_flush_summary(rec.spans, win.t0)}")
+    if any(q.where for q in pool):
+        log(f"window {_filter_summary(rec.spans)}")
 
     stats_mem = dev.memory_stats() or {}
     peak = int(stats_mem.get("peak_bytes_in_use", 0))
@@ -183,10 +189,11 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     t = time.perf_counter()
     answers = [system.to_answer(r.outcome) for r in win.requests]
     failed = sum(a is None for a in answers)
-    rng = corpus.host_rng(seed, 5)
-    n_sample = min(tr["check_sample"], len(win.requests))
-    pick = np.sort(rng.choice(len(win.requests), n_sample, replace=False))
-    ref = reference.Reference(vectors, draw.allowed)
+    pick = corpus.check_pick(seed, len(win.requests), tr["check_sample"])
+    n_sample = len(pick)
+    ref = reference.Reference(
+        data.vectors, data.policy.allowed,
+        None if data.attrs is None else data.attrs.eligible)
     checks = reference.compare(
         ref, [pool[win.requests[i].index % len(pool)] for i in pick],
         [answers[i] for i in pick], cfg["limits"])

@@ -30,6 +30,7 @@ class Span:
     t0: float                 # host clock, seconds (time.perf_counter)
     t1: float
     rows: int                 # queries in the flush / active in the launch
+    filtered: int = 0         # flush: queries with a where clause
     n: int = 0                # launch: node rows scanned
     dim: int = 0
     w: int = 0                # launch: auth-mask words
@@ -68,7 +69,8 @@ def wrap_search(store, rec: SpanRecorder) -> None:
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation(FLUSH):
             out = inner(queries, *args, **kw)
-        rec.add(Span(FLUSH, t0, time.perf_counter(), rows=len(queries)))
+        rec.add(Span(FLUSH, t0, time.perf_counter(), rows=len(queries),
+                     filtered=sum(bool(q.where) for q in queries)))
         return out
     store.search = search
 

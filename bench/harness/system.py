@@ -1,20 +1,23 @@
 """The system under test, reached only through its public entry points.
 
 This is the one module of the benchmark that imports the program: it turns
-the benchmark's raw data into the program's ``AccessPolicy``, builds the
-store with the configuration's lattice settings (``build_effveda`` + ``build_vector_storage`` with
-ScoreScan engines and the packed leftover shard), wraps the instances the
+the benchmark's raw data into the program's ``AccessPolicy`` and, where the
+configuration declares ``predicates``, into its ``PredicateSchema`` and
+packed attribute words; builds the store with the configuration's lattice
+settings (``build_effveda`` + ``build_vector_storage`` with ScoreScan
+engines and the packed leftover shard); turns a drawn query, its ``where``
+clause with it, into the program's ``Query``; wraps the instances the
 window drives with the benchmark's spans, warms the shapes the cell's
 traffic reaches, and hands out the program's scheduler.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from .corpus import PolicyDraw, QuerySpec
+from .corpus import TAG_OPS, Attributes, PolicyDraw, QuerySpec
 from .reference import Answer
 from .spans import SpanRecorder, wrap_engine, wrap_search
 
@@ -32,7 +35,36 @@ def to_policy(draw: PolicyDraw):
                         block_members=members)
 
 
-def build(config: Dict, vectors: np.ndarray, draw: PolicyDraw) -> Built:
+def to_schema(predicates: Dict):
+    """The program's schema for the configuration's ``predicates``: a tag
+    field's values are named by their index, a range field's bits are its
+    edges."""
+    from repro.core.predicate import PredicateSchema
+    return PredicateSchema.make(
+        tags={f: tuple(str(v) for v in range(spec["values"]))
+              for f, spec in predicates.get("tags", {}).items()},
+        ranges={f: spec["edges"]
+                for f, spec in predicates.get("ranges", {}).items()})
+
+
+def attr_words(schema, attrs: Attributes) -> np.ndarray:
+    """(N, P) uint32 attribute words: each row's tag value bit, and the
+    thermometer bit of every range edge at or below its value."""
+    words = np.zeros((attrs.n_rows, schema.n_words), np.uint32)
+
+    def set_bit(rows: np.ndarray, bit: int) -> None:
+        words[rows, bit // 32] |= np.uint32(1 << (bit % 32))
+    for f, index in attrs.tags.items():
+        for v in np.unique(index):
+            set_bit(index == v, schema.bit_of(f, str(v)))
+    for f, values in attrs.ranges.items():
+        for edge in dict(schema.range_fields)[f]:
+            set_bit(values >= edge, schema.bit_of(f, edge))
+    return words
+
+
+def build(config: Dict, vectors: np.ndarray, draw: PolicyDraw,
+          attrs: Optional[Attributes] = None) -> Built:
     from repro.ann.scorescan import scorescan_factory
     from repro.core import (HNSWCostModel, build_effveda,
                             build_vector_storage)
@@ -40,10 +72,15 @@ def build(config: Dict, vectors: np.ndarray, draw: PolicyDraw) -> Built:
     policy = to_policy(draw)
     cm = HNSWCostModel(lam_threshold=lat["lam_threshold"])
     result = build_effveda(policy, cm, beta=lat["beta"], k=lat["k"])
+    plane: Dict = {}
+    if attrs is not None:
+        schema = to_schema(config["predicates"])
+        plane = dict(pred_schema=schema, attr_words=attr_words(schema, attrs))
     store = build_vector_storage(
         result, vectors,
-        engine_factory=scorescan_factory(policy),
-        pack_leftovers=lat["pack_leftovers"])
+        engine_factory=scorescan_factory(
+            policy, attr_words=plane.get("attr_words")),
+        pack_leftovers=lat["pack_leftovers"], **plane)
     engines = [e for e in store.engines.values() if len(e)]
     if store.leftover_shard is not None and len(store.leftover_shard):
         engines.append(store.leftover_shard)
@@ -70,8 +107,11 @@ def instrument(built: Built, rec: SpanRecorder) -> None:
 
 
 def to_query(spec: QuerySpec):
+    """The program's ``Query``; a tag atom names its value by index."""
     from repro.core import Query
-    return Query(vector=spec.vector, roles=spec.roles, k=spec.k)
+    where = None if spec.where is None else tuple(
+        (op, f, str(v) if op in TAG_OPS else v) for op, f, v in spec.where)
+    return Query(vector=spec.vector, roles=spec.roles, k=spec.k, where=where)
 
 
 def to_answer(outcome) -> Optional[Answer]:
@@ -83,36 +123,43 @@ def to_answer(outcome) -> Optional[Answer]:
 
 
 def warm(built: Built, k: int, max_batch: int, dim: int,
-         seed: int = 0) -> int:
+         seed: int = 0, kinds: Sequence[bool] = (False,)) -> int:
     """Call every engine through the ``BatchEngine`` protocol at each
     padded query bucket up to ``max_batch``; then call the smallest node at
     every batch size from 1 to ``max_batch``, with bounds as node waves
     pass them and without as the packed shard's launch does: the kernel
-    wrapper's host-side operations take the unpadded size.  Returns the
-    number of calls."""
+    wrapper's host-side operations take the unpadded size.  A flush that
+    holds a filtered query passes require/forbid rows to every launch, so
+    each call is made once for each of the flush ``kinds`` the mix makes
+    (``corpus.flush_kinds``): unfiltered, and with require/forbid rows.
+    Returns the number of calls."""
     store = built.store
     rng = np.random.default_rng(seed)
     qs = rng.standard_normal((max_batch, dim)).astype(np.float32)
     masks = store.role_mask_rows([(0,)] * max_batch)
+    words = np.zeros((max_batch, store.pred_width), np.uint32)
     calls = 0
 
-    def one(eng, b, bounded=True):
+    def one(eng, b, bounded, filtered):
         bounds = np.full(b, np.inf, np.float32) if bounded else None
-        eng.search_masked_batch(qs[:b], k, masks[:b], bounds=bounds)
+        pred = dict(require=words[:b], forbid=words[:b]) if filtered else {}
+        eng.search_masked_batch(qs[:b], k, masks[:b], bounds=bounds, **pred)
 
     shard = store.leftover_shard
     for eng in built.engines:
         bq = eng.config.bq
         for b in range(bq, max_batch + bq, bq):
-            one(eng, min(b, max_batch), bounded=eng is not shard)
-            calls += 1
+            for filtered in kinds:
+                one(eng, min(b, max_batch), eng is not shard, filtered)
+                calls += 1
     nodes = [e for e in built.engines if e is not shard]
     if nodes:
         small = min(nodes, key=len)
         for b in range(1, max_batch + 1):
             for bounded in (True, False):
-                one(small, b, bounded)
-                calls += 1
+                for filtered in kinds:
+                    one(small, b, bounded, filtered)
+                    calls += 1
     return calls
 
 

@@ -26,19 +26,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def control_checks(cell, seed):
     from bench.harness import control, corpus, reference
     cfg, tr = cell.config, cell.traffic
-    draw = corpus.draw_policy(cfg["n_vectors"], cfg["n_roles"],
-                              cfg["n_permissions"], cfg["block_zipf"],
-                              cfg["perm_zipf"], cfg["max_roles_per_perm"],
-                              cfg["policy_seed"])
-    vectors = corpus.draw_vectors(seed, cfg["n_vectors"], cfg["dim"],
-                                  cfg["n_clusters"], cfg["center_scale"])
-    pool = corpus.draw_queries(seed, tr["pool"], vectors, draw, tr["k"],
-                               tr["union_share"], cfg["query_noise"])
-    rng = corpus.host_rng(seed, 5)
-    pick = rng.choice(len(pool), min(tr["check_sample"], len(pool)),
-                      replace=False)
-    qs = [pool[i] for i in pick]
-    ref = reference.Reference(vectors, draw.allowed)
+    data = corpus.draw_cell(cfg, tr, seed)
+    pick = corpus.check_pick(seed, len(data.pool), tr["check_sample"])
+    qs = [data.pool[i] for i in pick]
+    ref = reference.Reference(
+        data.vectors, data.policy.allowed,
+        None if data.attrs is None else data.attrs.eligible)
     return reference.compare(ref, qs, control.bf16_answers(ref, qs),
                              cfg["limits"])
 

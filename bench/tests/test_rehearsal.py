@@ -10,10 +10,10 @@ import pytest
 
 from bench.harness import runner, spec
 
-from .helpers import BIG_SEED, tiny_cell
+from .helpers import BIG_SEED, TEST_MIXES, tiny_cell
 
-MIXES = sorted(f[:-5] for f in os.listdir(os.path.join(spec.BENCH, "traffic"))
-               if f.endswith(".json"))
+MIXES = sorted([f[:-5] for f in os.listdir(os.path.join(spec.BENCH, "traffic"))
+                if f.endswith(".json")] + list(TEST_MIXES))
 
 
 def check_result(res, trace):
