@@ -239,29 +239,33 @@ def _prepare_batch(store: VectorStore, queries: Sequence[Query]):
     role_sets = [q.roles for q in queries]
     plans = [store.plan_for_roles(t) for t in role_sets]
     mask_cache: Dict[Tuple[int, ...], np.ndarray] = {}
-    for t in role_sets:
-        if t not in mask_cache:
-            mask_cache[t] = (store.authorized_mask(t[0]) if len(t) == 1
-                             else store.authorized_mask_multi(t))
+    with obs.span("search.authmask"):
+        for t in role_sets:
+            if t not in mask_cache:
+                mask_cache[t] = (store.authorized_mask(t[0]) if len(t) == 1
+                                 else store.authorized_mask_multi(t))
+        obs.count("role_sets", len(mask_cache))
     row_masks = [mask_cache[t] for t in role_sets]
     # (B,) uint32 single-word rows, or (B, W) packed word rows past 32 roles
     # (exact either way — no role aliasing); row selection `role_bits[rows]`
     # works identically for both layouts
     role_bits = store.role_mask_rows(role_sets)
     stats_rows = [SearchStats() for _ in range(b)]
-    pred_rows = store.predicate_rows(queries)
     pred_masks: Optional[List[Optional[np.ndarray]]] = None
-    if pred_rows is not None:
-        pmask_cache: Dict = {}
-        pred_masks = []
-        for q in queries:
-            if not q.where:
-                pred_masks.append(None)
-                continue
-            if q.where not in pmask_cache:
-                rf = store.compile_where(q.where)
-                pmask_cache[q.where] = store.predicate_mask(rf[0], rf[1])
-            pred_masks.append(pmask_cache[q.where])
+    with obs.span("search.predicate"):
+        pred_rows = store.predicate_rows(queries)
+        if pred_rows is not None:
+            pmask_cache: Dict = {}
+            pred_masks = []
+            for q in queries:
+                if not q.where:
+                    pred_masks.append(None)
+                    continue
+                if q.where not in pmask_cache:
+                    rf = store.compile_where(q.where)
+                    pmask_cache[q.where] = store.predicate_mask(rf[0], rf[1])
+                pred_masks.append(pmask_cache[q.where])
+            obs.count("clauses", len(pmask_cache))
     return (qs, ks, kmax, role_sets, plans, row_masks, role_bits, stats_rows,
             pred_rows, pred_masks)
 
@@ -313,14 +317,13 @@ def execute_queries(store: VectorStore, queries: Sequence[Query], *,
     ``coordinated_scan_search(store, q.vector, q.roles, q.k)``.
     """
     b = len(queries)
-    with obs.span("search.plan", rows=b) as sp:
+    with obs.span("search.plan", rows=b):
         (qs, ks, kmax, role_sets, plans, row_masks, role_bits,
          stats_rows, pred_rows, pred_masks) = _prepare_batch(store, queries)
         # invert plans: node -> rows, split per (row, node) purity against
         # the row's (multi-role) authorized mask
         pure_rows, impure_rows, sizes_cache = _classify_waves(
             store, plans, role_sets, row_masks, stats_rows)
-        sp.set(role_sets=len(set(role_sets)))
 
     topk = BatchTopK(b, kmax, ks=ks)
     if packed is True:

@@ -270,6 +270,7 @@ def _prep(queries, db, auth_bits, role_mask, bound, config, attr_bits,
             raise ValueError(
                 "require/forbid word rows need (N, P) attr_bits to filter on")
         p = node.p
+        obs.count("filtered_launches")
     qp, rp, bp, req, forb = _query_operands(
         _host(queries, np.float32), _host(role_mask, np.uint32),
         _host(np.inf if bound is None else bound, np.float32),
